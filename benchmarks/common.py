@@ -23,6 +23,7 @@ import numpy as np
 from repro.api import Result, Scenario
 from repro.api import run as api_run
 from repro.core.params import SchedulerParams
+from repro.launch.entry import enable_compile_cache
 
 # default benchmark fabric: FB-like (paper: 526 coflows / 150 ports);
 # --quick shrinks it so the full suite stays minutes on one CPU core.
@@ -116,6 +117,7 @@ def cli_bench(argv=None) -> "Tuple[Bench, str]":
     ap.add_argument("--engine", choices=("numpy", "jax"), default="numpy",
                     help="replay engine for the Saath side")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     return Bench(quick=not args.full), args.engine
 
 

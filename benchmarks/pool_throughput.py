@@ -39,15 +39,11 @@ import os
 import sys
 import time
 
-if __name__ == "__main__" and "--shards" in sys.argv \
-        and "XLA_FLAGS" not in os.environ:
-    # jax locks the device count at first initialization (triggered by
-    # the repro.api import below) — a sharded run must force the host
-    # devices BEFORE that
-    _n = int(sys.argv[sys.argv.index("--shards") + 1])
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={_n}"
+if __name__ == "__main__":
+    # before the `repro.api` import below initializes jax
+    from repro.launch.entry import force_host_devices
+
+    force_host_devices(sys.argv)
 
 import numpy as np
 
@@ -55,6 +51,7 @@ from benchmarks.common import record
 from repro.api import SaathSession, SessionPool, result_from_completions
 from repro.core.coflow import Coflow, Flow
 from repro.core.params import SchedulerParams
+from repro.launch.entry import enable_compile_cache
 
 # a serving-style fabric: narrow coflows (collective-sized widths) on
 # a small slab, many advances — the regime where per-dispatch fixed
@@ -152,6 +149,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--no-assert", action="store_true",
                     help="record numbers without gating on the speedup")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.shards > 1:
         import jax

@@ -23,6 +23,7 @@ from benchmarks import (fig2_out_of_sync, fig3_offline_policies,
                         fig13_fct_deviation, fig14_sensitivity,
                         table2_coordinator_latency)
 from benchmarks.common import Bench
+from repro.launch.entry import enable_compile_cache
 
 SUITES = [
     ("fig2", fig2_out_of_sync),
@@ -61,6 +62,7 @@ def main():
     ap.add_argument("--engine", choices=("numpy", "jax"), default="numpy",
                     help="replay engine for the Saath-side Scenarios")
     args = ap.parse_args()
+    enable_compile_cache()
     bench = Bench(quick=not args.full)
     t0 = time.time()
     failures = []

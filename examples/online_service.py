@@ -29,23 +29,19 @@ up automatically when XLA_FLAGS isn't already pinned by the caller.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 
-if __name__ == "__main__" and "--shards" in sys.argv \
-        and "XLA_FLAGS" not in os.environ:
-    # jax locks the device count at first initialization (triggered
-    # by the repro.api import below) — a sharded run must force the
-    # host devices BEFORE that
-    _n = int(sys.argv[sys.argv.index("--shards") + 1])
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={_n}"
+if __name__ == "__main__":
+    # before the `repro.api` import below initializes jax
+    from repro.launch.entry import force_host_devices
+
+    force_host_devices(sys.argv)
 
 import numpy as np
 
 from repro.api import SaathSession, SessionPool
+from repro.launch.entry import enable_compile_cache
 from repro.runtime.coflow_bridge import (RESOURCES, CollectiveCoflow,
                                          bridge_params,
                                          collective_to_coflow)
@@ -165,5 +161,6 @@ if __name__ == "__main__":
                     "this many devices (needs --tenants > 1, a "
                     "multiple of --shards)")
     args = ap.parse_args()
+    enable_compile_cache()
     main(seconds=args.seconds, seed=args.seed, backend=args.backend,
          tenants=args.tenants, shards=args.shards)

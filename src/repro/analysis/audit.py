@@ -54,7 +54,12 @@ FEATURES = (True, True, False, False)
 LF = 2
 
 
-def _canonical_slab(leaf_links: int = 0, sampling: bool = False):
+def _canonical_slab(leaf_links: int = 0, sampling: bool = False, *,
+                    b: int = B, f: int = F, c: int = C, p: int = P):
+    """A blank (b rows, f flows, c coflows, p ports) session slab and its
+    parameters; tests/test_tpu_compile.py compiles the same entrypoint
+    at serving sizes."""
+    B, F, C, P = b, f, c, p
     from repro.core import jax_coordinator as jc
     from repro.core.params import SchedulerParams
     from repro.fabric.jax_engine import EngineParams, EngineState

@@ -1,7 +1,7 @@
 """Recursive jaxpr traversal for the dispatch auditor.
 
 A jitted entrypoint lowers to a closed jaxpr whose equations may hold
-sub-jaxprs (pjit bodies, while/scan/cond branches, custom_jvp calls …)
+sub-jaxprs (jit bodies, while/scan/cond branches, custom_jvp calls …)
 inside `eqn.params`. The helpers here flatten that tree so the auditor
 can ask global questions about an entrypoint's whole traced extent:
 
@@ -20,7 +20,7 @@ from __future__ import annotations
 from collections import Counter
 from typing import Iterator, List
 
-import jax.core as jax_core
+import jax.extend.core as jax_core
 
 # Host-callback primitive names across jax versions. Matched by name so
 # the set survives primitive-object churn between releases.
@@ -32,7 +32,7 @@ CALLBACK_PRIMITIVES = frozenset({
 
 def iter_eqns(jaxpr) -> Iterator:
     """Yield every equation in `jaxpr` and, recursively, in any
-    sub-jaxpr reachable through equation params (pjit/scan/while/cond
+    sub-jaxpr reachable through equation params (jit/scan/while/cond
     bodies, closed and open alike)."""
     if hasattr(jaxpr, "jaxpr"):  # ClosedJaxpr -> Jaxpr
         jaxpr = jaxpr.jaxpr

@@ -594,10 +594,13 @@ def default_max_ticks(tb: TraceBatch, delta: float, slack: float = 4.0,
 def resolve_kernel(kernel: Optional[str],
                    use_pallas: bool) -> Optional[str]:
     """`use_pallas=True` opts the tick's inner ops (LCoF contention, the
-    max-min water-filling fill) into the Pallas kernels: the compiled
-    kernels on TPU, `interpret` mode elsewhere (the kernel BODY executed
-    on CPU — slow, parity-testing only). An explicit `kernel` force
-    always wins; default (False) keeps backend auto-dispatch."""
+    max-min water-filling fill) into the Pallas kernels, and an
+    out-of-domain shape then raises rather than running the reference
+    (`repro.kernels.ops`). On TPU that is the compiled kernels. Off TPU
+    it is `interpret` mode — the kernel BODY executed on CPU, slow, and
+    there only so the CPU tests can check the kernels' parity. An
+    explicit `kernel` force always wins; default (False) keeps backend
+    auto-dispatch."""
     if kernel is not None or not use_pallas:
         return kernel
     from repro.kernels.ops import _on_tpu

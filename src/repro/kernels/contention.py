@@ -4,15 +4,18 @@ k_c = #other coflows sharing >=1 sender or receiver port with coflow c.
 
 Shaped as an MXU problem: S = A_s A_s^T + A_r A_r^T over the (C, P)
 {0,1} incidence matrices, then k_c = row-count of S > 0 (minus self).
-The grid tiles (C x C) into (bc x bc) blocks; each block needs two
-(bc, P) incidence strips in VMEM and accumulates a (bc,) partial count
-into the output, so VMEM = 4 * bc * P * 4B + bc * 4B. With bc = 256 and
-P = 512 padded that is ~2 MB — far under the ~16 MB v5e VMEM budget,
-and both MXU operands are 128-aligned after ops.py padding.
-
-Table 2 of the paper shows LCoF ordering is half the coordinator's
-compute; this kernel is why the in-framework coordinator stays <<1 ms at
-512 ports x 4096 coflows (benchmarks/table2_coordinator_latency.py).
+The grid tiles (C x C) into (bc x bc) blocks; each step holds four
+(bc, Pp) incidence strips, double-buffered, and adds a lane-dense
+(1, bc) partial count to coflow block i's output. By that block
+arithmetic (the compiler does not report it) VMEM is 8 * bc * Pp * 4 B:
+2 MiB at the FB trace's 150 ports (Pp = 256), 4 MiB at table 2 (b)'s
+512 and 31 MiB at ops.CONTENTION_MAX_P = 3968, under the 32 MiB limit
+the call sets (v5e has 128 MiB). The v5e compiler accepts 3968 ports
+and refuses 3969 (Pp = 4096) for lack of VMEM, which sets the domain.
+Those compiles are kept as tests (tests/test_tpu_compile.py), and
+chip_smoke.py checks the kernel against the reference on the chip at
+4096 x 512 and 4096 x 3968. The kernel's time on the chip is not
+measured yet.
 """
 from __future__ import annotations
 
@@ -21,22 +24,30 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+VMEM_LIMIT = 32 * 1024 * 1024
 
 
-def _contention_kernel(a_s_row, a_r_row, a_s_col, a_r_col, k_ref, *, bc):
+def _contention_kernel(a_s_i, a_r_i, a_s_j, a_r_j, k_ref, *, bc):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    s = jnp.dot(a_s_row[...], a_s_col[...].T,
-                preferred_element_type=jnp.float32)
-    s += jnp.dot(a_r_row[...], a_r_col[...].T,
-                 preferred_element_type=jnp.float32)
-    blocks = (s > 0.5).astype(jnp.float32)   # (bc, bc) "c blocks c'"
+    # block (j, i) of S = A_s A_s^T + A_r A_r^T, contracted over ports
+    # (an NT matmul, no in-kernel transpose). S is symmetric, so column
+    # sums of block (j, i) are the row sums of block (i, j) and land
+    # lane-dense as a (1, bc) row of coflow i's counts.
+    nt = (((1,), (1,)), ((), ()))
+    s = jax.lax.dot_general(a_s_j[...], a_s_i[...], nt,
+                            preferred_element_type=jnp.float32)
+    s += jax.lax.dot_general(a_r_j[...], a_r_i[...], nt,
+                             preferred_element_type=jnp.float32)
+    blocks = (s > 0.5).astype(jnp.float32)   # (bc_j, bc_i) "c' blocks c"
     # on the diagonal block, remove self-contention
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (bc, bc), 0)
     col_ids = jax.lax.broadcasted_iota(jnp.int32, (bc, bc), 1)
     on_diag = (i == j) & (row_ids == col_ids)
     blocks = jnp.where(on_diag, 0.0, blocks)
-    partial = blocks.sum(axis=1)             # (bc,)
+    partial = blocks.sum(axis=0, keepdims=True)   # (1, bc_i)
 
     @pl.when(j == 0)
     def _init():
@@ -67,13 +78,14 @@ def contention_pallas(a_send: jax.Array, a_recv: jax.Array,
     grid = (Cp // bc, Cp // bc)
     strip = pl.BlockSpec((bc, Pp), lambda i, j: (i, 0))
     stripT = pl.BlockSpec((bc, Pp), lambda i, j: (j, 0))
-    out = pl.BlockSpec((bc,), lambda i, j: (i,))
+    out = pl.BlockSpec((1, bc), lambda i, j: (0, i))
     k = pl.pallas_call(
         functools.partial(_contention_kernel, bc=bc),
         grid=grid,
         in_specs=[strip, strip, stripT, stripT],
         out_specs=out,
-        out_shape=jax.ShapeDtypeStruct((Cp,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, Cp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(a_s, a_r, a_s, a_r)
-    return jnp.where(active, k[:C].astype(jnp.int32), 0)
+    return jnp.where(active, k[0, :C].astype(jnp.int32), 0)

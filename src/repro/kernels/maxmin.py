@@ -6,9 +6,14 @@ solve in VMEM — one grid step, `2P` fixed rounds of dense mat-vec
 products against the (P, F) one-hot incidence matrices (MXU work), no
 HBM traffic between rounds.
 
-Sized for the coordinator's working set (P <= 256 ports padded, F <=
-4096 flows padded: 2 * 256 * 4096 * 4 B = 8 MB of VMEM). ops.py falls
-back to ref.maxmin_ref beyond that.
+Its domain is P <= 256 ports and F <= 4096 flows (ops.MAXMIN_MAX_P/F):
+both (P, F) matrices are double-buffered in VMEM, 16 * P * F B, which
+the v5e compiler puts at 16.2 MiB at the cap, inside the 32 MiB limit
+the call sets; 512 ports or 8192 flows need 32.3-32.4 MiB and are
+refused (tests/test_tpu_compile.py). chip_smoke.py checks the kernel
+against the reference on the chip at the cap. Outside the domain, default
+dispatch runs ref.maxmin_ref and a forced Pallas path raises. The
+kernel's time on the chip is not measured yet.
 """
 from __future__ import annotations
 
@@ -17,8 +22,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BIG = 1e30
+VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _maxmin_kernel(src_ref, dst_ref, live_ref, bws_ref, bwr_ref, rates_ref,
@@ -84,6 +91,7 @@ def maxmin_pallas(src_onehot: jax.Array, dst_onehot: jax.Array,
                   pl.BlockSpec((Pp, 1), lambda _: (0, 0))],
         out_specs=pl.BlockSpec((1, Fp), lambda _: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, Fp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(src, dst, lv, bws, bwr)
     return rates[0, :F]

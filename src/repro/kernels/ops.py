@@ -5,11 +5,26 @@ the pure-jnp oracle in ref.py (identical semantics, lowerable on any
 backend — this is what the CPU dry-run and the smoke tests compile).
 Set ``force='pallas'`` / ``force='ref'`` / ``force='interpret'`` to pin
 a path (tests use 'interpret' to execute the kernel body on CPU).
+
+Kernel domains. The scheduler kernels hold whole operands in VMEM, so
+each compiles only up to a size (tests/test_tpu_compile.py compiles the
+limits for v5e):
+
+- ``contention``: padded ports P <= CONTENTION_MAX_P (any C: the grid
+  tiles coflows);
+- ``maxmin_rates``: P <= MAXMIN_MAX_P and F <= MAXMIN_MAX_F (one grid
+  step holds both (P, F) incidence matrices).
+
+Outside its domain, default dispatch runs the reference; an explicit
+``force='pallas'``/``'interpret'`` raises instead. Either way the path
+is visible: inside ``with record_paths() as log``, every trace of an op
+appends ``(op, shape, path)`` to ``log``.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.contention import contention_pallas
@@ -17,26 +32,52 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.maxmin import maxmin_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
-# maxmin kernel VMEM budget (see maxmin.py)
-_MAXMIN_MAX_P = 256
-_MAXMIN_MAX_F = 4096
+CONTENTION_MAX_P = 3968
+MAXMIN_MAX_P = 256
+MAXMIN_MAX_F = 4096
+
+_logs: list[list] = []
+
+
+@contextlib.contextmanager
+def record_paths():
+    """Collect ``(op, operand shape, path)`` for every scheduler op
+    traced inside the block. Recorded at trace time: a call served from
+    a compiled executable adds nothing, so a caller that wants every op
+    of a run clears jax's caches first (chip_smoke.py does)."""
+    log: list[tuple[str, tuple, str]] = []
+    _logs.append(log)
+    try:
+        yield log
+    finally:
+        _logs.remove(log)
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
-def _path(force: str | None) -> str:
-    if force is not None:
-        return force
-    return "pallas" if _on_tpu() else "ref"
+def _path(op: str, shape: tuple, force: str | None,
+          in_domain: bool = True) -> str:
+    if force == "ref":
+        p = "ref"
+    elif force is None:
+        p = "pallas" if _on_tpu() and in_domain else "ref"
+    elif not in_domain:
+        raise ValueError(
+            f"{op}: shape {shape} is outside the Pallas kernel's domain "
+            f"(see repro.kernels.ops); force='{force}' cannot run it — "
+            f"use force='ref' or default dispatch")
+    else:
+        p = force
+    for log in _logs:
+        log.append((op, tuple(shape), p))
+    return p
 
 
 def contention(a_send, a_recv, active, *, force: str | None = None):
-    p = _path(force)
+    C, P = a_send.shape
+    p = _path("contention", (C, P), force, P <= CONTENTION_MAX_P)
     if p == "ref":
         return ref.contention_ref(a_send, a_recv, active)
     return contention_pallas(a_send, a_recv, active,
@@ -45,9 +86,10 @@ def contention(a_send, a_recv, active, *, force: str | None = None):
 
 def maxmin_rates(src_onehot, dst_onehot, live, bw_send, bw_recv, *,
                  force: str | None = None):
-    p = _path(force)
     P, F = src_onehot.shape
-    if p == "ref" or P > _MAXMIN_MAX_P or F > _MAXMIN_MAX_F:
+    p = _path("maxmin", (P, F), force,
+              P <= MAXMIN_MAX_P and F <= MAXMIN_MAX_F)
+    if p == "ref":
         return ref.maxmin_ref(src_onehot, dst_onehot, live, bw_send, bw_recv)
     return maxmin_pallas(src_onehot, dst_onehot, live, bw_send, bw_recv,
                          interpret=(p == "interpret"))
@@ -55,7 +97,7 @@ def maxmin_rates(src_onehot, dst_onehot, live, bw_send, bw_recv, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
                     force: str | None = None, **kw):
-    p = _path(force)
+    p = _path("flash_attention", q.shape, force)
     if p == "ref":
         assert q_offset == 0, "ref path is offset-free (full prefill)"
         return ref.attention_ref(q, k, v, causal=causal)
@@ -65,11 +107,13 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 
 def ssd_scan(x, dt, a, b, c, *, init_state=None, force: str | None = None,
              **kw):
-    p = _path(force)
+    p = _path("ssd_scan", x.shape, force)
     if p == "ref":
         return ref.ssd_ref(x, dt, a, b, c, init_state=init_state)
     return ssd_scan_pallas(x, dt, a, b, c, init_state=init_state,
                            interpret=(p == "interpret"), **kw)
 
 
-__all__ = ["contention", "maxmin_rates", "flash_attention", "ssd_scan"]
+__all__ = ["contention", "maxmin_rates", "flash_attention", "ssd_scan",
+           "record_paths", "CONTENTION_MAX_P", "MAXMIN_MAX_P",
+           "MAXMIN_MAX_F"]

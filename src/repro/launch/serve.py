@@ -50,9 +50,9 @@ meet its budget is dropped with a counted decision, not queued into
 unbounded latency. Shed/deferral counters live in `TenantAggregates`
 (`shed`, `deferred`) and fleet-wide in `stats()`.
 
-The underlying pool's sharded slab and async dispatch pass straight
-through: ``CoflowServer(..., shards=N, async_dispatch=...,
-features=...)``.
+The underlying pool's sharded slab, async dispatch and fabric model
+pass straight through: ``CoflowServer(..., shards=N,
+async_dispatch=..., features=..., topology=...)``.
 
 CLI demo (CPU smoke):
   PYTHONPATH=src python -m repro.launch.serve --tenants 6 --seconds 0.4
@@ -65,21 +65,15 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import math
-import os
 import sys
 import time
 from typing import Dict, List, Optional, Sequence
 
-if __name__ == "__main__" and "--shards" in sys.argv \
-        and "XLA_FLAGS" not in os.environ:
-    # jax locks the device count at first initialization, which the
-    # `repro.api` import below triggers — a sharded CLI run must force
-    # the host devices BEFORE that (no-op when the caller already set
-    # XLA_FLAGS, e.g. `make pool-sharded` / CI)
-    _n = int(sys.argv[sys.argv.index("--shards") + 1])
-    if _n > 1:
-        os.environ["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={_n}"
+if __name__ == "__main__":
+    # before the `repro.api` import below initializes jax
+    from repro.launch.entry import force_host_devices
+
+    force_host_devices(sys.argv)
 
 import numpy as np
 
@@ -88,6 +82,7 @@ from repro.api.pool import PoolFullError
 from repro.api.session import CompletedCoflow
 from repro.core.coflow import Coflow
 from repro.core.params import SchedulerParams
+from repro.launch.entry import enable_compile_cache
 
 
 class AdmissionError(RuntimeError):
@@ -232,13 +227,13 @@ class CoflowServer:
                  kernel: Optional[str] = None, chunk: int = 32,
                  history_limit: int = 4096, shards: int = 1,
                  async_dispatch: bool = True,
-                 features: Optional[tuple] = None):
+                 features: Optional[tuple] = None, topology=None):
         self.pool = SessionPool(params, num_ports=num_ports,
                                 max_sessions=max_tenants,
                                 mechanisms=mechanisms, kernel=kernel,
                                 chunk=chunk, shards=shards,
                                 async_dispatch=async_dispatch,
-                                features=features)
+                                features=features, topology=topology)
         self.history_limit = int(history_limit)
         self._tenants: Dict[str, object] = {}
         self._pending: Dict[str, List[CompletedCoflow]] = {}
@@ -494,6 +489,7 @@ def main(argv=None) -> dict:
                     help="partition the slab row axis across this many "
                     "devices (CPU: forced host devices)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.traces.synth import tiny_trace
 

@@ -6,7 +6,7 @@ property-based test modules collect and run everywhere.  It is NOT a
 hypothesis reimplementation: no shrinking, no example database, no
 assume/filter machinery — just deterministic seeded-random sampling of
 the strategy combinators the tests actually import (`given`, `settings`,
-`strategies.integers/floats/lists/sampled_from/composite`).
+`example`, `strategies.integers/floats/lists/sampled_from/composite`).
 
 Determinism: example i of test f draws from ``random.Random(hash((f
 qualname, i)))`` so failures are reproducible run-to-run without any
@@ -102,6 +102,15 @@ class settings:  # noqa: N801 — mirrors hypothesis' lowercase decorator
         return fn
 
 
+def example(*args, **kwargs):
+    """An explicit case, run before the drawn ones."""
+    def decorate(fn):
+        fn._compat_examples = [(args, kwargs),
+                               *getattr(fn, "_compat_examples", ())]
+        return fn
+    return decorate
+
+
 def given(*strategies_args, **strategies_kw):
     def decorate(fn):
         cfg = getattr(fn, "_compat_settings", None)
@@ -109,6 +118,8 @@ def given(*strategies_args, **strategies_kw):
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
+            for ex_args, ex_kw in getattr(fn, "_compat_examples", ()):
+                fn(*args, *ex_args, **kwargs, **ex_kw)
             base = zlib.adler32(fn.__qualname__.encode())
             for i in range(n):
                 rng = random.Random((base << 20) + i)

@@ -174,8 +174,6 @@ def test_f64_cast_injected_into_session_advance_fails_gate():
     audit.  Tracing runs under enable_x64 because with x64 disabled the
     cast is silently dropped from the jaxpr — the exact failure mode
     the gate exists to catch before it ships to an x64-enabled host."""
-    from jax.experimental import enable_x64
-
     from repro.fabric.jax_engine import _run_session_block
 
     def poisoned():
@@ -185,7 +183,7 @@ def test_f64_cast_injected_into_session_advance_fails_gate():
             leaf = jax.tree_util.tree_leaves(out)[0]
             bad = jax.lax.convert_element_type(leaf, jnp.float64)
             return out, bad
-        with enable_x64():
+        with jax.enable_x64(True):
             return jax.make_jaxpr(drifted)(*_session_advance_inputs())
 
     manifest = json.loads(au.default_manifest_path().read_text())

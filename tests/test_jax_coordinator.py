@@ -1,7 +1,8 @@
 """The jitted coordinator agrees with the numpy Saath reference."""
 import numpy as np
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 
+from repro.core.coflow import Coflow, Flow, Trace
 from repro.core.params import SchedulerParams
 from repro.core.policies import make_policy
 from repro.fabric.engine import Simulator
@@ -27,6 +28,15 @@ def test_admission_matches_numpy(trace):
 
 @given(traces())
 @settings(max_examples=15, deadline=None)
+# three coflows on port 0->0, one exactly at start_threshold: the
+# saath-jax policy's known fidelity gap against numpy (ROADMAP §3)
+@example(trace=Trace(num_ports=6, coflows=[
+    Coflow(cid=0, arrival=1.0,
+           flows=[Flow(fid=f, src=0, dst=0, size=1.0) for f in range(5)]),
+    Coflow(cid=1, arrival=0.0,
+           flows=[Flow(fid=f, src=0, dst=0, size=1.0) for f in (5, 6)]),
+    Coflow(cid=2, arrival=0.0,
+           flows=[Flow(fid=7, src=0, dst=0, size=4.0)])]))
 def test_full_sim_close_to_numpy(trace):
     """End-to-end on the FULL reference config (per-flow work
     conservation + §4.3 dynamics re-queue, both defaults): the jitted

@@ -54,6 +54,32 @@ def test_maxmin_sweep(P, F):
     assert (np.array(got)[~np.array(live)] == 0).all()
 
 
+@pytest.mark.parametrize("force", ["pallas", "interpret"])
+def test_forced_kernel_outside_its_domain_raises(force):
+    P, F = ops.MAXMIN_MAX_P + 1, 8
+    with pytest.raises(ValueError, match="outside the Pallas kernel"):
+        ops.maxmin_rates(jnp.zeros((P, F)), jnp.zeros((P, F)),
+                         jnp.zeros((F,), bool), jnp.ones(P), jnp.ones(P),
+                         force=force)
+    C, P = 4, ops.CONTENTION_MAX_P + 1
+    with pytest.raises(ValueError, match="outside the Pallas kernel"):
+        ops.contention(jnp.zeros((C, P)), jnp.zeros((C, P)),
+                       jnp.ones(C, bool), force=force)
+
+
+def test_default_dispatch_records_the_path_it_took():
+    """Off TPU, and past a kernel's domain anywhere, default dispatch
+    runs the reference, and says so."""
+    P, F = ops.MAXMIN_MAX_P + 1, 8
+    with ops.record_paths() as log:
+        ops.maxmin_rates(jnp.zeros((P, F)), jnp.zeros((P, F)),
+                         jnp.zeros((F,), bool), jnp.ones(P), jnp.ones(P))
+        ops.contention(jnp.zeros((4, 6)), jnp.zeros((4, 6)),
+                       jnp.ones(4, bool), force="interpret")
+    assert log == [("maxmin", (P, F), "ref"),
+                   ("contention", (4, 6), "interpret")]
+
+
 def test_maxmin_matches_numpy_waterfill():
     from repro.core.policies.base import maxmin_waterfill
     from repro.fabric.state import FlowTable

@@ -95,12 +95,8 @@ def test_scheduled_psum_preserves_values_and_orders():
     def f(*g):
         return tuple(scheduled_psum(list(g), bks, waves, "data"))
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        shard_map = jax.shard_map
-    else:  # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
-    fn = shard_map(f, mesh=mesh, in_specs=tuple(P() for _ in flat),
-                   out_specs=tuple(P() for _ in flat))
+    fn = jax.shard_map(f, mesh=mesh, in_specs=tuple(P() for _ in flat),
+                       out_specs=tuple(P() for _ in flat))
     out = jax.jit(fn)(*flat)
     for a, b in zip(out, flat):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))

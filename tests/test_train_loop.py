@@ -61,11 +61,8 @@ def test_elastic_reshard_roundtrip(tmp_path):
 
     tree = {"w": jnp.arange(64.0).reshape(8, 8)}
     save(str(tmp_path), 1, tree)
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.6 explicit-axes API
-        mesh = jax.make_mesh((1, 1), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    else:  # jax 0.4.x: meshes are implicitly Auto on every axis
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     back = restore(str(tmp_path), 1, tree, mesh=mesh,
                    specs={"w": P("data", "model")})
     np.testing.assert_array_equal(np.asarray(back["w"]),
