@@ -100,9 +100,9 @@ PROTOCOL: Dict[str, Dict[str, str]] = {
         "_ep_stack": "stacked per-row EngineParams; None means stale",
         "_ticks": "lazy host mirror of per-row device tick counters",
         "_fin": "lazy host mirror of the per-row completion bitmap",
-        "_ctl": "deferred async ctl handle: (tick, finished) device "
-                "arrays parked by _dispatch_async, consumed once by "
-                "_sync_ctl",
+        "_ctl": "deferred async ctl handle: (tick, finished, work "
+                "counters) device arrays and the dispatch number, parked "
+                "by _dispatch_async, consumed once by _sync_ctl",
         "_pend_rows": "rows with an in-flight async horizon "
                       "(row -> (session, n_end))",
         "_fresh": "sessions whose completion bitmap changed since "
@@ -956,9 +956,9 @@ SEEDED_MUTATIONS: Tuple[Tuple[str, str, str, str, str], ...] = (
      "            self._tb = self._place(self._tb)",
      R_CACHE),
     ("double-consumed-ctl-handle", "api/pool.py",
-     "        tick_dev, fin_dev = self._ctl\n"
+     "        tick_dev, fin_dev, counts, n = self._ctl\n"
      "        self._ctl = None",
-     "        tick_dev, fin_dev = self._ctl",
+     "        tick_dev, fin_dev, counts, n = self._ctl",
      R_HANDLE),
     ("unaccounted-transfer", "api/pool.py",
      "    @_io_accounted\n    def host_view",

@@ -111,6 +111,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.sanitize import accounted_transfer
 from repro.core.params import SchedulerParams
@@ -123,6 +124,14 @@ REBASE_TICKS = 1 << 20
 # more than this is split into epochs (each split re-packs and
 # re-bases, so `tickf` arithmetic never leaves the f32-exact range)
 MAX_REL_TICKS = 1 << 22
+
+# host spans of the pool's work, on the profiler's clock (a profiler
+# trace places them beside the device ops they wait on or feed)
+SPAN_SYNC_CTL = "saath.pool.sync_ctl"   # host blocked on a dispatch
+SPAN_GATHER = "saath.pool.gather"       # stale rows gathered and synced
+SPAN_STAGE = "saath.pool.stage"         # numpy packing of rows to upload
+SPAN_UPLOAD = "saath.pool.upload"       # row scatters, slab uploads
+SPAN_DISPATCH = "saath.pool.dispatch"   # the session_advance enqueue
 
 
 def _io_accounted(method):
@@ -265,16 +274,22 @@ class SessionPool:
         self._sampling = bool(self._features_now[4])
         # async dispatch chain: the parked device ctl handles of the
         # most recent dispatch, plus the rows awaiting its download
-        self._ctl = None               # (tick_dev, fin_dev) | None
+        # (tick_dev, fin_dev, work counters, dispatch number) | None
+        self._ctl = None
         self._pend_rows: dict = {}     # row -> (session, global n_end)
         # sessions whose `_new_done` is set: the O(1) index behind the
         # completion bitmap, so a poll over a clean fleet never walks
         # the roster (B per-session polls per step must not cost B^2)
         self._fresh: set = set()
         # host<->device transfer accounting (benchmarks assert on this)
+        # and the dispatches' work counters (`jax_engine.WorkCounts`,
+        # added at each ctl download): event steps of the longest device
+        # loop, and per-row sums of open-lane steps, admission trips and
+        # work-conservation trips
         self.io = dict(full_uploads=0, row_uploads=0, row_downloads=0,
                        upload_bytes=0, download_bytes=0, ctl_bytes=0,
-                       dispatches=0)
+                       dispatches=0, event_steps=0, lane_steps=0,
+                       admit_trips=0, wc_trips=0)
 
     def _resolve(self, params: Optional[SchedulerParams],
                  mechanisms: Optional[dict]) -> tuple:
@@ -468,16 +483,16 @@ class SessionPool:
             for r, (s, n_end) in work.items():
                 ne[r] = min(n_end, s._epoch + MAX_REL_TICKS) - s._epoch
             tb, ep = self._dispatch_slab()
-            state, _ = self._je.session_advance(
-                self._state, tb, ep, n_end=ne,
-                chunk=self.chunk, kernel=self.kernel,
-                features=self._features_now, mesh=self._mesh)
+            n = self.io["dispatches"] + 1
+            with TraceAnnotation(SPAN_DISPATCH, dispatch=n):
+                state, _, counts = self._je.session_advance(
+                    self._state, tb, ep, n_end=ne,
+                    chunk=self.chunk, kernel=self.kernel,
+                    features=self._features_now, mesh=self._mesh)
             self._state = state          # stays device-resident
-            self.io["dispatches"] += 1
-            tick_h = np.array(state.tick).reshape(-1)
-            fin_h = np.array(state.finished)
-            fin_h = fin_h.reshape(-1, fin_h.shape[-1])
-            self.io["ctl_bytes"] += tick_h.nbytes + fin_h.nbytes
+            self.io["dispatches"] = n
+            tick_h, fin_h = self._read_ctl(state.tick, state.finished,
+                                           counts, n)
             nxt = {}
             for r, (s, n_end) in work.items():
                 s._tick = s._epoch + int(tick_h[r])
@@ -510,13 +525,19 @@ class SessionPool:
         for r, (s, n_end) in work.items():
             ne[r] = n_end - s._epoch     # caller checked the rel cap
         tb, ep = self._dispatch_slab()
-        state, _ = self._je.session_advance(
-            self._state, tb, ep, n_end=ne,
-            chunk=self.chunk, kernel=self.kernel,
-            features=self._features_now, mesh=self._mesh, block=False)
+        n = self.io["dispatches"] + 1
+        # the work counters ride the chain on the device: a dispatch
+        # starts from the still-parked ctl's, so ONE download covers them
+        counts = self._ctl[2] if self._ctl is not None else None
+        with TraceAnnotation(SPAN_DISPATCH, dispatch=n):
+            state, _, counts = self._je.session_advance(
+                self._state, tb, ep, n_end=ne,
+                chunk=self.chunk, kernel=self.kernel,
+                features=self._features_now, mesh=self._mesh,
+                block=False, counts=counts)
         self._state = state              # stays device-resident
-        self.io["dispatches"] += 1
-        self._ctl = (state.tick, state.finished)
+        self.io["dispatches"] = n
+        self._ctl = (state.tick, state.finished, counts, n)
         for r, (s, n_end) in work.items():
             s._host_stale = True
             self._pend_rows[r] = (s, n_end)
@@ -525,35 +546,57 @@ class SessionPool:
     def _sync_ctl(self) -> None:
         """Consume the deferred control download of the async dispatch
         chain: ONE host transfer of the tiny (tick, finished) mirrors
-        covers every dispatch enqueued since the last sync. MUST run
+        and the work counters covers every dispatch enqueued since the
+        last sync. MUST run
         before anything reads or writes the host ctl mirrors — poll's
         completion scan, snapshot gathers, dirty-row scatters and
         rebuilds (which overwrite mirror rows), `host_view` — so a
         stale parked ctl can never clobber fresher mirror writes."""
         if self._ctl is None:
             return
-        tick_dev, fin_dev = self._ctl
+        tick_dev, fin_dev, counts, n = self._ctl
         self._ctl = None
-        tick_h = np.array(tick_dev).reshape(-1)
-        fin_h = np.array(fin_dev)
-        fin_h = fin_h.reshape(-1, fin_h.shape[-1])
-        self.io["ctl_bytes"] += tick_h.nbytes + fin_h.nbytes
+        tick_h, fin_h = self._read_ctl(tick_dev, fin_dev, counts, n)
         pend, self._pend_rows = self._pend_rows, {}
         short = []
         for r, (s, n_end) in pend.items():
             if s._row != r or self._sessions[r] is not s:
                 continue          # released (maybe recycled) row
-            s._tick = s._epoch + int(tick_h[r])
+            s._tick = s._epoch + int(tick_h[r])  # saath: lint-ok(host-pull-unaccounted): host mirror from _read_ctl, which accounts the download
             if (fin_h[r] != self._fin[r]).any():
                 s._new_done = True   # poll must gather this row
                 self._fresh.add(s)
-            if s._tick < n_end and not bool(fin_h[r].all()):
+            if s._tick < n_end and not bool(fin_h[r].all()):  # saath: lint-ok(host-pull-unaccounted): host mirror from _read_ctl
                 short.append((r, s._tick, n_end))
         self._ticks, self._fin = tick_h, fin_h
         if short:
             raise RuntimeError(
                 f"async session_advance stopped short of its horizon "
                 f"on rows {short} (step budget exhausted?)")
+
+    @_io_accounted
+    def _read_ctl(self, tick_dev, fin_dev, counts, n: int) -> tuple:
+        """Download dispatch `n`'s control mirrors (per-row ticks and
+        completion bitmap) together with the work counters it carries,
+        waiting for the device; charge the bytes to `ctl_bytes` and add
+        the counters into `io`. Returns the flat (tick, finished) host
+        mirrors."""
+        with TraceAnnotation(SPAN_SYNC_CTL, dispatch=n):
+            tick_h, fin_h, counts = jax.device_get(
+                (tick_dev, fin_dev, counts))
+        tick_h = np.array(tick_h).reshape(-1)
+        fin_h = np.array(fin_h)
+        fin_h = fin_h.reshape(-1, fin_h.shape[-1])
+        self.io["ctl_bytes"] += (tick_h.nbytes + fin_h.nbytes
+                                 + _tree_nbytes(counts))
+        # every row of a slab (or shard) pays each loop iteration: the
+        # longest loop's count is the serial device work; the per-lane
+        # counters sum over rows
+        self.io["event_steps"] += int(counts.event_steps.max())
+        self.io["lane_steps"] += int(counts.lane_steps.sum())
+        self.io["admit_trips"] += int(counts.admit_trips.sum())
+        self.io["wc_trips"] += int(counts.wc_trips.sum())
+        return tick_h, fin_h
 
     @_io_accounted
     def _plan_tick(self, sess) -> np.ndarray:
@@ -643,48 +686,58 @@ class SessionPool:
             return
         # re-packing reads the host entries: sync the dirty rows first
         self._materialize(dirty)
-        tb_rows, st_rows = [], []
-        for r in sorted(self._blank_rows):
-            self._blank_scratch()
-            tb_rows.append((r, row_of(self._scratch, 0)))
-            st_rows.append((r, self._blank_state_row()))
-        self._blank_rows.clear()
-        for s in dirty:
-            if s._tb_dirty:
-                self._pack_row_np(self._scratch_tb(), 0, s)
-                tb_rows.append((s._row, row_of(self._scratch, 0)))
-            st_rows.append((s._row, self._state_row(s)))
-            s._state_dirty = False
-        for r, row in st_rows:
-            self._ticks[r] = int(row.tick)
-            self._fin[r] = row.finished
-        # ONE SCATTER QUEUE PER SHARD: staged rows funnel through their
-        # owning shard's fused scatter (the unsharded pool keeps the
-        # single fused call — exactly the pre-shard dispatch shape)
-        per = self.max_sessions // self.shards
-        buckets: dict = {}
-        for r, row in tb_rows:
-            buckets.setdefault(r // per, ([], []))[0].append((r, row))
-        for r, row in st_rows:
-            buckets.setdefault(r // per, ([], []))[1].append((r, row))
-        st = self._state_flat()
-        for sh in sorted(buckets):
-            tb_g, st_g = buckets[sh]
-            st_idx = np.array([r for r, _ in st_g], np.int32)
-            st_payload = jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs), *[p for _, p in st_g])
-            self.io["upload_bytes"] += _tree_nbytes(st_payload)
-            if tb_g:
-                # one fused scatter dispatch covers both trees
-                tb_idx = np.array([r for r, _ in tb_g], np.int32)
-                tb_payload = stack_rows([p for _, p in tb_g])
-                self.io["row_uploads"] += len(tb_g)
-                self.io["upload_bytes"] += _tree_nbytes(tb_payload)
-                self._tb, st = self._je.scatter_rows(
-                    (self._tb, st), (tb_idx, st_idx),
-                    (tb_payload, st_payload))
-            else:
-                st = self._je.scatter_rows(st, st_idx, st_payload)
+        with TraceAnnotation(SPAN_STAGE):
+            tb_rows, st_rows = [], []
+            for r in sorted(self._blank_rows):
+                self._blank_scratch()
+                tb_rows.append((r, row_of(self._scratch, 0)))
+                st_rows.append((r, self._blank_state_row()))
+            self._blank_rows.clear()
+            for s in dirty:
+                if s._tb_dirty:
+                    self._pack_row_np(self._scratch_tb(), 0, s)
+                    tb_rows.append((s._row, row_of(self._scratch, 0)))
+                st_rows.append((s._row, self._state_row(s)))
+                s._state_dirty = False
+            for r, row in st_rows:
+                self._ticks[r] = int(row.tick)
+                self._fin[r] = row.finished
+            # ONE SCATTER QUEUE PER SHARD: staged rows funnel through
+            # their owning shard's fused scatter (the unsharded pool
+            # keeps the single fused call — exactly the pre-shard
+            # dispatch shape)
+            per = self.max_sessions // self.shards
+            buckets: dict = {}
+            for r, row in tb_rows:
+                buckets.setdefault(r // per, ([], []))[0].append((r, row))
+            for r, row in st_rows:
+                buckets.setdefault(r // per, ([], []))[1].append((r, row))
+            # per shard: (tb_idx, tb_payload, st_idx, st_payload), the
+            # TraceBatch pair None where the shard re-packed no row
+            staged = []
+            for sh in sorted(buckets):
+                tb_g, st_g = buckets[sh]
+                st_idx = np.array([r for r, _ in st_g], np.int32)
+                st_payload = jax.tree_util.tree_map(
+                    lambda *xs: np.stack(xs), *[p for _, p in st_g])
+                self.io["upload_bytes"] += _tree_nbytes(st_payload)
+                tb_idx = tb_payload = None
+                if tb_g:
+                    tb_idx = np.array([r for r, _ in tb_g], np.int32)
+                    tb_payload = stack_rows([p for _, p in tb_g])
+                    self.io["row_uploads"] += len(tb_g)
+                    self.io["upload_bytes"] += _tree_nbytes(tb_payload)
+                staged.append((tb_idx, tb_payload, st_idx, st_payload))
+        with TraceAnnotation(SPAN_UPLOAD):
+            st = self._state_flat()
+            for tb_idx, tb_payload, st_idx, st_payload in staged:
+                if tb_idx is not None:
+                    # one fused scatter dispatch covers both trees
+                    self._tb, st = self._je.scatter_rows(
+                        (self._tb, st), (tb_idx, st_idx),
+                        (tb_payload, st_payload))
+                else:
+                    st = self._je.scatter_rows(st, st_idx, st_payload)
         if self._sharding is not None:
             # keep the slab pinned to its row sharding between
             # dispatches (a no-op when the scatter preserved it) and
@@ -719,30 +772,34 @@ class SessionPool:
 
         self._materialize()
         self._scratch = None
-        tb = empty_batch(self.max_sessions,
-                         flow_capacity=self._F_cap,
-                         coflow_capacity=self._C_cap,
-                         port_capacity=self.num_ports,
-                         leaf_links=self._Lf,
-                         sampling=self._sampling)
-        rows = [self._blank_state_row()
-                for _ in range(self.max_sessions)]
-        self._blank_rows.clear()
-        for s in self.sessions:
-            s._tb_dirty = True
-            self._pack_row_np(tb, s._row, s)
-            rows[s._row] = self._state_row(s)
-            s._state_dirty = False
-        state = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *rows)
+        with TraceAnnotation(SPAN_STAGE):
+            tb = empty_batch(self.max_sessions,
+                             flow_capacity=self._F_cap,
+                             coflow_capacity=self._C_cap,
+                             port_capacity=self.num_ports,
+                             leaf_links=self._Lf,
+                             sampling=self._sampling)
+            rows = [self._blank_state_row()
+                    for _ in range(self.max_sessions)]
+            self._blank_rows.clear()
+            for s in self.sessions:
+                s._tb_dirty = True
+                self._pack_row_np(tb, s._row, s)
+                rows[s._row] = self._state_row(s)
+                s._state_dirty = False
+            state = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
+                                           *rows)
         self.io["full_uploads"] += 1
         self.io["upload_bytes"] += _tree_nbytes(tb) + _tree_nbytes(state)
         # the upload pins the row sharding: each shard receives exactly
         # its own rows (sharding=None -> default single-device slab);
         # the state uploads directly in dispatch layout (the fold is a
         # free host-side numpy reshape)
-        self._tb = jax.device_put(tb, self._sharding)
-        self._tb_disp = None
-        self._state = jax.device_put(self._fold(state), self._sharding)
+        with TraceAnnotation(SPAN_UPLOAD):
+            self._tb = jax.device_put(tb, self._sharding)
+            self._tb_disp = None
+            self._state = jax.device_put(self._fold(state),
+                                         self._sharding)
         self._ticks = state.tick.copy()
         self._fin = state.finished.copy()
 
@@ -896,16 +953,17 @@ class SessionPool:
                  and (s._new_done or not completions_only)]
         if not stale:
             return
-        idx = np.array([s._row for s in stale], np.int32)
-        rows = self._je.gather_rows(self._state_flat(), idx)
-        host = jax.tree_util.tree_map(np.asarray, rows)
-        self.io["row_downloads"] += len(stale)
-        self.io["download_bytes"] += _tree_nbytes(host)
-        for j, s in enumerate(stale):
-            self._sync_row(s, host, j)
-            s._host_stale = False
-            s._new_done = False
-            self._fresh.discard(s)
+        with TraceAnnotation(SPAN_GATHER):
+            idx = np.array([s._row for s in stale], np.int32)
+            rows = self._je.gather_rows(self._state_flat(), idx)
+            host = jax.tree_util.tree_map(np.asarray, rows)
+            self.io["row_downloads"] += len(stale)
+            self.io["download_bytes"] += _tree_nbytes(host)
+            for j, s in enumerate(stale):
+                self._sync_row(s, host, j)
+                s._host_stale = False
+                s._new_done = False
+                self._fresh.discard(s)
 
     def _sync_row(self, s, st, j: int) -> None:
         """Mirror row `j` of the gathered host state into session `s`'s

@@ -35,6 +35,16 @@ from repro.kernels import ops
 
 BIG = jnp.float32(1e30)
 
+# `jax.named_scope`s of the tick's parts: every op of a compiled tick
+# carries the innermost one in its metadata (`op_name`), which is how a
+# device trace attributes time to the code that spent it
+SCOPE_QUEUES = "saath.tick.queues"
+SCOPE_CONTENTION = "saath.tick.contention"
+SCOPE_ORDER = "saath.tick.order"
+SCOPE_ADMIT = "saath.tick.admit"
+SCOPE_WC_ORDER = "saath.tick.wc_order"
+SCOPE_WC_FILL = "saath.tick.wc_fill"
+
 
 class CoordParams(NamedTuple):
     """Static coordinator parameters (see core.params.SchedulerParams)."""
@@ -212,117 +222,132 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
     C, P = batch.cnt_s.shape
     act = batch.active
 
-    # D3: per-flow thresholds (Eq. 1) — compare m_c * N_c against Q_q^hi;
-    # the Fig. 10 A/N ablation (per_flow=0) uses Aalo total-bytes queues
-    qval = batch.m * batch.width.astype(jnp.float32)
-    if batch.total is not None:
-        qval = jnp.where(dp.per_flow > 0, qval, batch.total)
-    q = _queue_of(qval, th)
-    # §4.3 cluster dynamics: a coflow with both finished and live flows
-    # re-queues by its estimated remaining length (the caller-computed
-    # finished-flow-median m_hat, Eq. 1 form) — approximate SRTF that can
-    # move a coflow back UP the queues, matching Saath._assign_queues.
-    if batch.mixed is not None:
-        q_dyn = _queue_of(batch.m_dyn * batch.width.astype(jnp.float32),
-                          th)
-        use_dyn = (dp.requeue > 0) & batch.mixed & act
-        if dp.clairvoyant is not None:
-            # mixed-mode dispatch: only clairvoyant rows may read the
-            # exact-size median estimate
-            use_dyn = use_dyn & (dp.clairvoyant > 0)
-        q = jnp.where(use_dyn, q_dyn, q)
-    if batch.s_mixed is not None:
-        # learned-mode §4.3: re-queue from the pilot-sampling estimate.
-        # Compiled in only when some row runs non-clairvoyant; the
-        # clairvoyant gate keeps known-size rows bit-identical inside a
-        # mixed vmap/stacked dispatch.
-        q_smp = _queue_of(batch.s_m * batch.width.astype(jnp.float32), th)
-        cl = (dp.clairvoyant if dp.clairvoyant is not None
-              else jnp.float32(1.0))
-        q = jnp.where((cl <= 0) & (dp.requeue > 0) & batch.s_mixed & act,
-                      q_smp, q)
-    q = jnp.where(act, q, jnp.maximum(state.queue, 0))
+    with jax.named_scope(SCOPE_QUEUES):
+        # D3: per-flow thresholds (Eq. 1) — compare m_c * N_c against
+        # Q_q^hi; the Fig. 10 A/N ablation (per_flow=0) uses Aalo
+        # total-bytes queues
+        qval = batch.m * batch.width.astype(jnp.float32)
+        if batch.total is not None:
+            qval = jnp.where(dp.per_flow > 0, qval, batch.total)
+        q = _queue_of(qval, th)
+        # §4.3 cluster dynamics: a coflow with both finished and live
+        # flows re-queues by its estimated remaining length (the
+        # caller-computed finished-flow-median m_hat, Eq. 1 form) —
+        # approximate SRTF that can move a coflow back UP the queues,
+        # matching Saath._assign_queues.
+        if batch.mixed is not None:
+            q_dyn = _queue_of(
+                batch.m_dyn * batch.width.astype(jnp.float32), th)
+            use_dyn = (dp.requeue > 0) & batch.mixed & act
+            if dp.clairvoyant is not None:
+                # mixed-mode dispatch: only clairvoyant rows may read the
+                # exact-size median estimate
+                use_dyn = use_dyn & (dp.clairvoyant > 0)
+            q = jnp.where(use_dyn, q_dyn, q)
+        if batch.s_mixed is not None:
+            # learned-mode §4.3: re-queue from the pilot-sampling
+            # estimate. Compiled in only when some row runs
+            # non-clairvoyant; the clairvoyant gate keeps known-size rows
+            # bit-identical inside a mixed vmap/stacked dispatch.
+            q_smp = _queue_of(
+                batch.s_m * batch.width.astype(jnp.float32), th)
+            cl = (dp.clairvoyant if dp.clairvoyant is not None
+                  else jnp.float32(1.0))
+            q = jnp.where(
+                (cl <= 0) & (dp.requeue > 0) & batch.s_mixed & act,
+                q_smp, q)
+        q = jnp.where(act, q, jnp.maximum(state.queue, 0))
 
-    # D5: FIFO-derived deadlines, refreshed on queue entry (spans are
-    # precomputed host-side in DynCoordParams, matching
-    # core.queues.min_queue_residence).
-    entered = act & (q != state.queue)
-    K = th.shape[0]
-    cq = jnp.zeros((K,), jnp.float32).at[q].add(act.astype(jnp.float32))
-    t_min = dp.span[q] / (jnp.maximum(batch.width, 1) * dp.bw_ref)
-    deadline = jnp.where(
-        entered, now + dp.deadline_factor * jnp.maximum(cq[q], 1.0) * t_min,
-        state.deadline)
-    expired = act & (now >= deadline)
+        # D5: FIFO-derived deadlines, refreshed on queue entry (spans are
+        # precomputed host-side in DynCoordParams, matching
+        # core.queues.min_queue_residence).
+        entered = act & (q != state.queue)
+        K = th.shape[0]
+        cq = jnp.zeros((K,), jnp.float32).at[q].add(
+            act.astype(jnp.float32))
+        t_min = dp.span[q] / (jnp.maximum(batch.width, 1) * dp.bw_ref)
+        deadline = jnp.where(
+            entered,
+            now + dp.deadline_factor * jnp.maximum(cq[q], 1.0) * t_min,
+            state.deadline)
+        expired = act & (now >= deadline)
 
     # LCoF contention (Pallas kernel on TPU)
-    k = ops.contention((batch.cnt_s > 0).astype(jnp.float32),
-                       (batch.cnt_r > 0).astype(jnp.float32),
-                       act, force=kernel)
+    with jax.named_scope(SCOPE_CONTENTION):
+        k = ops.contention((batch.cnt_s > 0).astype(jnp.float32),
+                           (batch.cnt_r > 0).astype(jnp.float32),
+                           act, force=kernel)
 
-    # order: expired first (by deadline — a float lexsort operand, zero
-    # for everyone else), then (queue, k, stability, arrival); coflows
-    # with no live ports and inactive coflows last, so perm's first
-    # `n_live` entries double as the admission processing list.
-    # jnp.lexsort: last key is primary.
-    hp = act & ((batch.cnt_s > 0).any(axis=1)
-                | (batch.cnt_r > 0).any(axis=1))
-    arr_rank = batch.arrival
-    not_running = (~state.running).astype(jnp.int32)
-    primary = jnp.where(~hp, 2, jnp.where(expired, 0, 1))
-    dl_key = jnp.where(expired & hp, deadline, 0.0)
-    # lcof=0 (Fig. 10 A/N): FIFO within queue — contention and stability
-    # keys drop out, leaving (queue, arrival) exactly as the reference
-    lc = dp.lcof > 0
-    key_q = jnp.where(expired, 0, q)
-    key_k = jnp.where(expired | ~lc, 0, k)
-    key_st = jnp.where(expired | ~lc, 0, not_running)
-    # arr_rank stays a live key for EXPIRED coflows too: exact f32
-    # deadline ties (same tick, same queue, same width) must break by a
-    # layout-independent total order — the final arange(C) tie-break is
-    # the slab POSITION, which differs between an offline pack (cid
-    # order) and a session slab (submission order), and would fork an
-    # otherwise bitwise-identical incremental replay.
-    perm = jnp.lexsort((jnp.arange(C), arr_rank, key_st, key_k, key_q,
-                        dl_key, primary))
+    with jax.named_scope(SCOPE_ORDER):
+        # order: expired first (by deadline — a float lexsort operand,
+        # zero for everyone else), then (queue, k, stability, arrival);
+        # coflows with no live ports and inactive coflows last, so perm's
+        # first `n_live` entries double as the admission processing
+        # list. jnp.lexsort: last key is primary.
+        hp = act & ((batch.cnt_s > 0).any(axis=1)
+                    | (batch.cnt_r > 0).any(axis=1))
+        arr_rank = batch.arrival
+        not_running = (~state.running).astype(jnp.int32)
+        primary = jnp.where(~hp, 2, jnp.where(expired, 0, 1))
+        dl_key = jnp.where(expired & hp, deadline, 0.0)
+        # lcof=0 (Fig. 10 A/N): FIFO within queue — contention and
+        # stability keys drop out, leaving (queue, arrival) exactly as
+        # the reference
+        lc = dp.lcof > 0
+        key_q = jnp.where(expired, 0, q)
+        key_k = jnp.where(expired | ~lc, 0, k)
+        key_st = jnp.where(expired | ~lc, 0, not_running)
+        # arr_rank stays a live key for EXPIRED coflows too: exact f32
+        # deadline ties (same tick, same queue, same width) must break by
+        # a layout-independent total order — the final arange(C)
+        # tie-break is the slab POSITION, which differs between an
+        # offline pack (cid order) and a session slab (submission
+        # order), and would fork an otherwise bitwise-identical
+        # incremental replay.
+        perm = jnp.lexsort((jnp.arange(C), arr_rank, key_st, key_k,
+                            key_q, dl_key, primary))
 
-    # D1/D2: all-or-none admission with MADD equal rates, processed in
-    # `perm` priority order. Only a coflow with live ports can change the
-    # carry (a missed or port-less coflow leaves `avail` untouched), so
-    # the sequential pass runs as a while_loop over the COMPACTED live
-    # list: trip count = live coflows, not padded C. Results are
-    # identical to a full scan over perm — skipped entries are no-ops —
-    # and the fleet engine's per-tick cost drops with occupancy.
-    min_rate = dp.min_rate_frac * dp.bw_ref
-    cnt = jnp.concatenate([batch.cnt_s, batch.cnt_r], axis=1)   # (C, 2P)
-    avail0 = jnp.concatenate([batch.bw_s, batch.bw_r])          # (2P,)
-    if batch.cnt_x is not None:
-        # leaf-spine: the MADD min also runs over the coflow's
-        # uplink/downlink counts — same arithmetic, a wider concat
-        cnt = jnp.concatenate([cnt, batch.cnt_x], axis=1)  # (C, 2P+Lx)
-        avail0 = jnp.concatenate([avail0, batch.bw_x])
-    has = cnt > 0
-    inv = jnp.where(has, 1.0 / jnp.maximum(cnt, 1e-9), 0.0)
-    bigm = jnp.where(has, 0.0, BIG)
-    clist = perm                          # live coflows lead (see above)
-    n_live = hp.sum().astype(jnp.int32)
-    zC = jnp.zeros((C,), jnp.float32)
+    with jax.named_scope(SCOPE_ADMIT):
+        # D1/D2: all-or-none admission with MADD equal rates, processed
+        # in `perm` priority order. Only a coflow with live ports can
+        # change the carry (a missed or port-less coflow leaves `avail`
+        # untouched), so the sequential pass runs as a while_loop over
+        # the COMPACTED live list: trip count = live coflows, not padded
+        # C. Results are identical to a full scan over perm — skipped
+        # entries are no-ops — and the fleet engine's per-tick cost
+        # drops with occupancy.
+        min_rate = dp.min_rate_frac * dp.bw_ref
+        cnt = jnp.concatenate([batch.cnt_s, batch.cnt_r], axis=1)  # (C, 2P)
+        avail0 = jnp.concatenate([batch.bw_s, batch.bw_r])         # (2P,)
+        if batch.cnt_x is not None:
+            # leaf-spine: the MADD min also runs over the coflow's
+            # uplink/downlink counts — same arithmetic, a wider concat
+            cnt = jnp.concatenate([cnt, batch.cnt_x], axis=1)  # (C, 2P+Lx)
+            avail0 = jnp.concatenate([avail0, batch.bw_x])
+        has = cnt > 0
+        inv = jnp.where(has, 1.0 / jnp.maximum(cnt, 1e-9), 0.0)
+        bigm = jnp.where(has, 0.0, BIG)
+        clist = perm                      # live coflows lead (see above)
+        n_live = hp.sum().astype(jnp.int32)
+        zC = jnp.zeros((C,), jnp.float32)
 
-    def admit_body(s):
-        k, avail, rate_, adm = s
-        c = clist[k]
-        r = (avail * inv[c] + bigm[c]).min()
-        ok = (r >= min_rate) & (r < BIG)
-        r = jnp.where(ok, r, 0.0)
-        return (k + 1, avail - r * cnt[c], rate_.at[c].set(r),
-                adm.at[c].set(ok))
+        def admit_body(s):
+            k, avail, rate_, adm = s
+            c = clist[k]
+            r = (avail * inv[c] + bigm[c]).min()
+            ok = (r >= min_rate) & (r < BIG)
+            r = jnp.where(ok, r, 0.0)
+            return (k + 1, avail - r * cnt[c], rate_.at[c].set(r),
+                    adm.at[c].set(ok))
 
-    _, avail, rate, admitted = jax.lax.while_loop(
-        lambda s: s[0] < n_live, admit_body,
-        (jnp.int32(0), avail0, zC, jnp.zeros((C,), bool)))
+        _, avail, rate, admitted = jax.lax.while_loop(
+            lambda s: s[0] < n_live, admit_body,
+            (jnp.int32(0), avail0, zC, jnp.zeros((C,), bool)))
 
     # D4 work conservation over the missed list (lines 18-23), gated by
     # dp.wc via the trip count (zero iterations when the switch is off).
+    # `n_cand` counts the fill's serial trips (0 for the max-min fill,
+    # which has none).
     wc_on = dp.wc > 0
     if flows is None:
         # coflow-granular fallback: one equal rate across all live flows
@@ -335,9 +360,11 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
             r = jnp.where(ok, r, 0.0)
             return (j + 1, avail_ - r * cnt[c], wc.at[c].set(r))
 
-        _, _, wc_rate = jax.lax.while_loop(
-            lambda s: s[0] < jnp.where(wc_on, n_live, 0), wc_body,
-            (jnp.int32(0), avail, zC))
+        with jax.named_scope(SCOPE_WC_FILL):
+            n_cand = jnp.where(wc_on, n_live, 0)
+            _, _, wc_rate = jax.lax.while_loop(
+                lambda s: s[0] < n_cand, wc_body,
+                (jnp.int32(0), avail, zC))
         wc_flow = None
     else:
         # per-flow greedy fill, the reference's greedy_flow_alloc: live
@@ -353,9 +380,10 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
         # two gathers + two scalar updates.
         wc_rate = zC
         avail_s, avail_r = avail[:P], avail[P:2 * P]
-        missed_c = hp & ~admitted
         F = flows.src.shape[0]
-        cand0 = flows.live & missed_c[flows.cid] & wc_on
+        with jax.named_scope(SCOPE_WC_ORDER):
+            missed_c = hp & ~admitted
+            cand0 = flows.live & missed_c[flows.cid] & wc_on
         if wc_fill == "maxmin":
             # max-min fair water-filling over the leftover flows (the
             # in-network allocation family), via the shared
@@ -364,33 +392,39 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
             # progressive filling otherwise. Incidence rows stack ports
             # then uplinks/downlinks; the sentinel leaf id Lf one-hots
             # to a zero column, so intra-leaf flows see ports only.
-            a_send = jax.nn.one_hot(flows.src, P, axis=0,
-                                    dtype=jnp.float32)
-            a_recv = jax.nn.one_hot(flows.dst, P, axis=0,
-                                    dtype=jnp.float32)
-            bw_s_ext, bw_r_ext = avail_s, avail_r
-            if flows.up is not None:
-                Lf = batch.cnt_x.shape[1] // 2
-                a_send = jnp.concatenate(
-                    [a_send, jax.nn.one_hot(flows.up, Lf, axis=0,
-                                            dtype=jnp.float32)])
-                a_recv = jnp.concatenate(
-                    [a_recv, jax.nn.one_hot(flows.dn, Lf, axis=0,
-                                            dtype=jnp.float32)])
-                bw_s_ext = jnp.concatenate([avail_s, avail[2 * P:
-                                                           2 * P + Lf]])
-                bw_r_ext = jnp.concatenate([avail_r, avail[2 * P + Lf:]])
-            wc_flow = ops.maxmin_rates(
-                a_send, a_recv, cand0, bw_s_ext, bw_r_ext, force=kernel)
-            wc_flow = jnp.where(cand0, wc_flow, 0.0)
+            with jax.named_scope(SCOPE_WC_FILL):
+                a_send = jax.nn.one_hot(flows.src, P, axis=0,
+                                        dtype=jnp.float32)
+                a_recv = jax.nn.one_hot(flows.dst, P, axis=0,
+                                        dtype=jnp.float32)
+                bw_s_ext, bw_r_ext = avail_s, avail_r
+                if flows.up is not None:
+                    Lf = batch.cnt_x.shape[1] // 2
+                    a_send = jnp.concatenate(
+                        [a_send, jax.nn.one_hot(flows.up, Lf, axis=0,
+                                                dtype=jnp.float32)])
+                    a_recv = jnp.concatenate(
+                        [a_recv, jax.nn.one_hot(flows.dn, Lf, axis=0,
+                                                dtype=jnp.float32)])
+                    bw_s_ext = jnp.concatenate(
+                        [avail_s, avail[2 * P:2 * P + Lf]])
+                    bw_r_ext = jnp.concatenate(
+                        [avail_r, avail[2 * P + Lf:]])
+                wc_flow = ops.maxmin_rates(
+                    a_send, a_recv, cand0, bw_s_ext, bw_r_ext,
+                    force=kernel)
+                wc_flow = jnp.where(cand0, wc_flow, 0.0)
+            n_cand = jnp.int32(0)
         else:
-            invp = jnp.argsort(perm)      # priority rank of each coflow
-            # three separate sort keys (candidates first, coflow
-            # priority, flow index) — a fused invp[cid]*F + i key would
-            # overflow int32 near the advertised 4k x 256k scale
-            flist = jnp.lexsort((jnp.arange(F), invp[flows.cid],
-                                 (~cand0).astype(jnp.int32)))
-            n_cand = cand0.sum().astype(jnp.int32)
+            with jax.named_scope(SCOPE_WC_ORDER):
+                invp = jnp.argsort(perm)  # priority rank of each coflow
+                # three separate sort keys (candidates first, coflow
+                # priority, flow index) — a fused invp[cid]*F + i key
+                # would overflow int32 near the advertised 4k x 256k
+                # scale
+                flist = jnp.lexsort((jnp.arange(F), invp[flows.cid],
+                                     (~cand0).astype(jnp.int32)))
+                n_cand = cand0.sum().astype(jnp.int32)
 
             if flows.up is None:
                 def wc_flow_body(s):
@@ -401,10 +435,11 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
                     return (i + 1, a_s.at[sp].add(-r),
                             a_r.at[dq].add(-r), wcf.at[f].set(r))
 
-                _, _, _, wc_flow = jax.lax.while_loop(
-                    lambda s: s[0] < n_cand, wc_flow_body,
-                    (jnp.int32(0), avail_s, avail_r,
-                     jnp.zeros((F,), jnp.float32)))
+                with jax.named_scope(SCOPE_WC_FILL):
+                    _, _, _, wc_flow = jax.lax.while_loop(
+                        lambda s: s[0] < n_cand, wc_flow_body,
+                        (jnp.int32(0), avail_s, avail_r,
+                         jnp.zeros((F,), jnp.float32)))
             else:
                 # leaf-spine: the fill is also capped by the flow's
                 # uplink/downlink residuals. Sentinel leaf id Lf
@@ -412,9 +447,6 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
                 # never link-capped (and the slot absorbs their
                 # subtracts harmlessly).
                 Lf = batch.cnt_x.shape[1] // 2
-                a_u0 = jnp.concatenate([avail[2 * P:2 * P + Lf],
-                                        BIG[None]])
-                a_d0 = jnp.concatenate([avail[2 * P + Lf:], BIG[None]])
 
                 def wc_flow_body(s):
                     i, a_s, a_r, a_u, a_d, wcf = s
@@ -428,14 +460,22 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
                             a_r.at[dq].add(-r), a_u.at[u].add(-r),
                             a_d.at[d].add(-r), wcf.at[f].set(r))
 
-                _, _, _, _, _, wc_flow = jax.lax.while_loop(
-                    lambda s: s[0] < n_cand, wc_flow_body,
-                    (jnp.int32(0), avail_s, avail_r, a_u0, a_d0,
-                     jnp.zeros((F,), jnp.float32)))
+                with jax.named_scope(SCOPE_WC_FILL):
+                    a_u0 = jnp.concatenate([avail[2 * P:2 * P + Lf],
+                                            BIG[None]])
+                    a_d0 = jnp.concatenate([avail[2 * P + Lf:],
+                                            BIG[None]])
+                    _, _, _, _, _, wc_flow = jax.lax.while_loop(
+                        lambda s: s[0] < n_cand, wc_flow_body,
+                        (jnp.int32(0), avail_s, avail_r, a_u0, a_d0,
+                         jnp.zeros((F,), jnp.float32)))
 
     new_state = CoordState(queue=jnp.where(act, q, state.queue),
                            deadline=deadline, running=admitted)
+    # n_live / n_cand: the admission and work-conservation loops' trip
+    # counts this tick (the work counters `jax_engine` sums per row)
     out = {"rate": rate, "wc_rate": wc_rate, "wc_flow": wc_flow,
            "admitted": admitted, "queue": q, "contention": k,
-           "expired": expired, "order": perm}
+           "expired": expired, "order": perm,
+           "n_live": n_live, "n_cand": n_cand}
     return new_state, out

@@ -45,6 +45,12 @@ from repro.traces.batch import TraceBatch, pack
 # slip a tick and desynchronize the replay from the float64 reference.
 REL_EPS = 1e-5
 
+# `jax.named_scope`s of the session loop and of the tick's engine-side
+# parts (the coordinator's own parts are scoped in `jax_coordinator`)
+SCOPE_SESSION = "saath.session"
+SCOPE_VIEWS = "saath.tick.views"
+SCOPE_HORIZON = "saath.tick.horizon"
+
 
 class EngineParams(NamedTuple):
     """Traced scheduler knobs: a DynCoordParams plus the δ grid step.
@@ -131,6 +137,25 @@ class EngineResult(NamedTuple):
         from repro.fabric.metrics import nan_row_mean
 
         return nan_row_mean(self.cct)
+
+
+class WorkCounts(NamedTuple):
+    """Per-row work counters of one session dispatch (each (B,) int32,
+    or folded (shards, B/shards) on the pmap path), accumulated in the
+    session while_loop's carry:
+
+    - `event_steps`: the loop's iterations (every row of a slab, or of a
+      shard, pays each one);
+    - `lane_steps`: iterations in which the row's lane was open (short
+      of its horizon with unfinished coflows);
+    - `admit_trips`: admission while_loop trips (live coflows per tick);
+    - `wc_trips`: work-conservation fill trips (candidate flows per
+      tick for the per-flow fill, live coflows for the coflow-granular
+      one, 0 for the max-min fill)."""
+    event_steps: jax.Array
+    lane_steps: jax.Array
+    admit_trips: jax.Array
+    wc_trips: jax.Array
 
 
 # ---- single-trace tick ---------------------------------------------------
@@ -324,7 +349,7 @@ def _tick(state: EngineState, tb: TraceBatch, ep: EngineParams,
           with_ablations: bool = False,
           wc_maxmin: bool = False,
           with_sampling: bool = False,
-          n_end: Optional[jax.Array] = None) -> EngineState:
+          n_end: Optional[jax.Array] = None):
     """Advance one *event step*: schedule at the current δ tick, find the
     next instant the schedule could change (arrival, flow completion,
     queue-threshold crossing, starvation deadline — the reference
@@ -353,6 +378,9 @@ def _tick(state: EngineState, tb: TraceBatch, ep: EngineParams,
     numpy session oracle — instead of re-evaluating the boundary tick,
     so incremental replay is bitwise the offline scan. `None` (offline
     replay) compiles both the cap and the pending machinery out.
+
+    Returns (new state, (admission trips, work-conservation trips)) of
+    the step's coordinator tick (`tick_core`'s loop trip counts).
     """
     session = n_end is not None
     delta = ep.delta
@@ -360,152 +388,155 @@ def _tick(state: EngineState, tb: TraceBatch, ep: EngineParams,
     now = state.t0 + tickf * delta
     eps_t = 1e-3 * delta
     can = tickf < n_end if session else None
-    batch, flows, active, live, livef = _views(
-        state, tb, now, eps_t, per_flow_wc=per_flow_wc,
-        with_dynamics=with_dynamics, with_ablations=with_ablations,
-        with_sampling=with_sampling, active_gate=can)
+    with jax.named_scope(SCOPE_VIEWS):
+        batch, flows, active, live, livef = _views(
+            state, tb, now, eps_t, per_flow_wc=per_flow_wc,
+            with_dynamics=with_dynamics, with_ablations=with_ablations,
+            with_sampling=with_sampling, active_gate=can)
     total = batch.total
     coord, out = jc.tick_core(state.coord, batch, now, ep.dp,
                               kernel=kernel, flows=flows,
                               wc_fill="maxmin" if wc_maxmin else "greedy")
-    # per-flow rates: MADD equal rate for admitted coflows + the work-
-    # conservation fill (flow-granular when per_flow_wc, else the
-    # coflow-granular equal rate; both already gated by dp.wc)
-    r_f = out["rate"][tb.cid] * livef
-    if per_flow_wc:
-        r_f = r_f + out["wc_flow"]
-    else:
-        r_f = r_f + out["wc_rate"][tb.cid] * livef
-    served = live & (r_f > 0)
-    rem = tb.size - state.sent
+    trips = (out["n_live"], out["n_cand"])
+    with jax.named_scope(SCOPE_HORIZON):
+        # per-flow rates: MADD equal rate for admitted coflows + the work-
+        # conservation fill (flow-granular when per_flow_wc, else the
+        # coflow-granular equal rate; both already gated by dp.wc)
+        r_f = out["rate"][tb.cid] * livef
+        if per_flow_wc:
+            r_f = r_f + out["wc_flow"]
+        else:
+            r_f = r_f + out["wc_rate"][tb.cid] * livef
+        served = live & (r_f > 0)
+        rem = tb.size - state.sent
 
-    # ---- event horizon (mirrors Simulator._next_event + Saath
-    # progress_events, vectorized) -------------------------------------
-    inf = jnp.float32(jnp.inf)
-    t_fin = jnp.min(jnp.where(served, now + rem / jnp.maximum(r_f, 1e-30),
-                              inf))
-    # queue-threshold crossing, per the active threshold rule: flow f of
-    # coflow c crosses when sent_f reaches Q_q^hi / N_c (Eq. 1), or —
-    # for the per_flow=0 Aalo-queue ablation — when the coflow's TOTAL
-    # bytes reach Q_q^hi (q = the post-assignment queue)
-    q = jnp.maximum(coord.queue, 0)
-    thq = ep.dp.thresholds[q]
-    lim = (thq / jnp.maximum(tb.width, 1).astype(jnp.float32))[tb.cid]
-    dt_th = jnp.where(served & jnp.isfinite(lim) & (lim > state.sent),
-                      (lim - state.sent) / jnp.maximum(r_f, 1e-30), inf)
-    t_th = now + jnp.min(dt_th)
-    if with_ablations:
-        R_c = _segment_sum(r_f, tb.flow_lo, tb.flow_hi)
-        dt_tot = jnp.where(active & (R_c > 0) & jnp.isfinite(thq)
-                           & (thq > total),
-                           (thq - total) / jnp.maximum(R_c, 1e-30), inf)
-        t_th = now + jnp.where(ep.dp.per_flow > 0, jnp.min(dt_th),
-                               jnp.min(dt_tot))
-    t_dl = jnp.min(jnp.where(active & (coord.deadline > now + eps_t),
-                             coord.deadline, inf))
-    t_arr = jnp.min(jnp.where(tb.coflow_valid & (tb.arrival > now + eps_t),
-                              tb.arrival, inf))
-    t_ev = jnp.minimum(jnp.minimum(t_fin, t_th), jnp.minimum(t_dl, t_arr))
-    # the pilot-sampling estimate drifts continuously too (rem = f_hat -
-    # sent), so learned mode needs the same bounded re-evaluation
-    # cadence as the §4.3 exact-median machinery
-    jump = DYNAMICS_JUMP_TICKS if (with_dynamics or with_sampling) \
-        else MAX_JUMP_TICKS
-    n_ev = jnp.where(jnp.isfinite(t_ev),
-                     jnp.ceil((t_ev - state.t0) / delta - 1e-4),
-                     tickf + jump)
-    # the jump cap bounds RE-EVALUATION cadence on live state (§4.3
-    # drift; pathological-lane guard). With nothing live there is
-    # nothing to re-evaluate — an idle gap (e.g. the run-up from the
-    # t=0 grid origin to a late first arrival) is jumped in ONE step,
-    # bounded only by the f32-exact tick range.
-    idle_jump = jnp.float32(IDLE_JUMP_TICKS)
-    hi = tickf + jnp.where(jnp.any(live), jnp.float32(jump), idle_jump)
-    n_un = jnp.clip(n_ev, tickf + 1.0, hi)  # uncapped horizon
+        # ---- event horizon (mirrors Simulator._next_event + Saath
+        # progress_events, vectorized) -------------------------------------
+        inf = jnp.float32(jnp.inf)
+        t_fin = jnp.min(jnp.where(served, now + rem / jnp.maximum(r_f, 1e-30),
+                                  inf))
+        # queue-threshold crossing, per the active threshold rule: flow f of
+        # coflow c crosses when sent_f reaches Q_q^hi / N_c (Eq. 1), or —
+        # for the per_flow=0 Aalo-queue ablation — when the coflow's TOTAL
+        # bytes reach Q_q^hi (q = the post-assignment queue)
+        q = jnp.maximum(coord.queue, 0)
+        thq = ep.dp.thresholds[q]
+        lim = (thq / jnp.maximum(tb.width, 1).astype(jnp.float32))[tb.cid]
+        dt_th = jnp.where(served & jnp.isfinite(lim) & (lim > state.sent),
+                          (lim - state.sent) / jnp.maximum(r_f, 1e-30), inf)
+        t_th = now + jnp.min(dt_th)
+        if with_ablations:
+            R_c = _segment_sum(r_f, tb.flow_lo, tb.flow_hi)
+            dt_tot = jnp.where(active & (R_c > 0) & jnp.isfinite(thq)
+                               & (thq > total),
+                               (thq - total) / jnp.maximum(R_c, 1e-30), inf)
+            t_th = now + jnp.where(ep.dp.per_flow > 0, jnp.min(dt_th),
+                                   jnp.min(dt_tot))
+        t_dl = jnp.min(jnp.where(active & (coord.deadline > now + eps_t),
+                                 coord.deadline, inf))
+        t_arr = jnp.min(jnp.where(tb.coflow_valid & (tb.arrival > now + eps_t),
+                                  tb.arrival, inf))
+        t_ev = jnp.minimum(jnp.minimum(t_fin, t_th), jnp.minimum(t_dl, t_arr))
+        # the pilot-sampling estimate drifts continuously too (rem = f_hat -
+        # sent), so learned mode needs the same bounded re-evaluation
+        # cadence as the §4.3 exact-median machinery
+        jump = DYNAMICS_JUMP_TICKS if (with_dynamics or with_sampling) \
+            else MAX_JUMP_TICKS
+        n_ev = jnp.where(jnp.isfinite(t_ev),
+                         jnp.ceil((t_ev - state.t0) / delta - 1e-4),
+                         tickf + jump)
+        # the jump cap bounds RE-EVALUATION cadence on live state (§4.3
+        # drift; pathological-lane guard). With nothing live there is
+        # nothing to re-evaluate — an idle gap (e.g. the run-up from the
+        # t=0 grid origin to a late first arrival) is jumped in ONE step,
+        # bounded only by the f32-exact tick range.
+        idle_jump = jnp.float32(IDLE_JUMP_TICKS)
+        hi = tickf + jnp.where(jnp.any(live), jnp.float32(jump), idle_jump)
+        n_un = jnp.clip(n_ev, tickf + 1.0, hi)  # uncapped horizon
 
-    if not session:
-        n_next = n_un
-        r_use, anchor_t, anchor_tick = r_f, now, tickf
-        anchor_sent, coord_new = state.sent, coord
-    else:
-        cap = jnp.maximum(n_end, tickf + 1.0)
-        # pending-horizon resume: if the previous advance capped a
-        # schedule interval, keep integrating the STORED rates from the
-        # STORED anchor to the stored horizon — or to the δ-quantized
-        # tick of an arrival submitted since the anchor (a discrete
-        # event the offline loop would have stopped at) — instead of
-        # re-evaluating the boundary tick.
-        pend_t = state.t0 + state.pend_tick * delta
-        late = jnp.min(jnp.where(
-            tb.coflow_valid & (tb.arrival > pend_t + eps_t),
-            tb.arrival, inf))
-        late_n = jnp.maximum(jnp.ceil((late - state.t0) / delta - 1e-4),
-                             state.pend_tick + 1.0)
-        stop = jnp.minimum(state.pend_next, late_n)
-        resuming = (state.pend_next > tickf) & (stop > tickf)
-        n_next = jnp.where(resuming, jnp.minimum(stop, cap),
-                           jnp.minimum(n_un, cap))
-        r_use = jnp.where(resuming, state.rate, r_f)
-        anchor_t = jnp.where(resuming, pend_t, now)
-        anchor_tick = jnp.where(resuming, state.pend_tick, tickf)
-        anchor_sent = jnp.where(resuming, state.pend_sent, state.sent)
-        # a resumed interval does NOT re-invoke the coordinator: queue
-        # moves / deadline refreshes happen only at evaluation instants,
-        # exactly as in the offline loop
-        coord_new = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(resuming, a, b), state.coord, coord)
-        served = live & (r_use > 0)
+        if not session:
+            n_next = n_un
+            r_use, anchor_t, anchor_tick = r_f, now, tickf
+            anchor_sent, coord_new = state.sent, coord
+        else:
+            cap = jnp.maximum(n_end, tickf + 1.0)
+            # pending-horizon resume: if the previous advance capped a
+            # schedule interval, keep integrating the STORED rates from the
+            # STORED anchor to the stored horizon — or to the δ-quantized
+            # tick of an arrival submitted since the anchor (a discrete
+            # event the offline loop would have stopped at) — instead of
+            # re-evaluating the boundary tick.
+            pend_t = state.t0 + state.pend_tick * delta
+            late = jnp.min(jnp.where(
+                tb.coflow_valid & (tb.arrival > pend_t + eps_t),
+                tb.arrival, inf))
+            late_n = jnp.maximum(jnp.ceil((late - state.t0) / delta - 1e-4),
+                                 state.pend_tick + 1.0)
+            stop = jnp.minimum(state.pend_next, late_n)
+            resuming = (state.pend_next > tickf) & (stop > tickf)
+            n_next = jnp.where(resuming, jnp.minimum(stop, cap),
+                               jnp.minimum(n_un, cap))
+            r_use = jnp.where(resuming, state.rate, r_f)
+            anchor_t = jnp.where(resuming, pend_t, now)
+            anchor_tick = jnp.where(resuming, state.pend_tick, tickf)
+            anchor_sent = jnp.where(resuming, state.pend_sent, state.sent)
+            # a resumed interval does NOT re-invoke the coordinator: queue
+            # moves / deadline refreshes happen only at evaluation instants,
+            # exactly as in the offline loop
+            coord_new = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(resuming, a, b), state.coord, coord)
+            served = live & (r_use > 0)
 
-    # ---- integrate the constant rates across the interval, ANCHORED
-    # at the evaluation instant: sent/fct are recomputed from the
-    # anchor, so an interval split by n_end caps integrates to exactly
-    # the same f32 values as the offline single-shot step -------------
-    dt = (n_next - anchor_tick) * delta
-    rem_a = tb.size - anchor_sent
-    adv = r_use * dt
-    fin = served & (adv >= rem_a - REL_EPS * tb.size)
-    fct = jnp.where(fin, anchor_t + rem_a / jnp.maximum(r_use, 1e-30),
-                    state.fct)
-    sent = jnp.where(fin, tb.size,
-                     jnp.minimum(tb.size, anchor_sent + adv))
-    done = state.done | fin
+        # ---- integrate the constant rates across the interval, ANCHORED
+        # at the evaluation instant: sent/fct are recomputed from the
+        # anchor, so an interval split by n_end caps integrates to exactly
+        # the same f32 values as the offline single-shot step -------------
+        dt = (n_next - anchor_tick) * delta
+        rem_a = tb.size - anchor_sent
+        adv = r_use * dt
+        fin = served & (adv >= rem_a - REL_EPS * tb.size)
+        fct = jnp.where(fin, anchor_t + rem_a / jnp.maximum(r_use, 1e-30),
+                        state.fct)
+        sent = jnp.where(fin, tb.size,
+                         jnp.minimum(tb.size, anchor_sent + adv))
+        done = state.done | fin
 
-    # coflow completions: CCT = last FCT - arrival (fct is 0 until a
-    # flow completes, so the masked row-max sees only completed flows)
-    undone = _segment_sum((tb.flow_valid & ~done).astype(jnp.float32),
-                          tb.flow_lo, tb.flow_hi)
-    newly = active & (undone < 0.5)
-    last_fct = _segment_max(fct * tb.flow_valid, tb)
-    cct = jnp.where(newly, last_fct - tb.arrival, state.cct)
+        # coflow completions: CCT = last FCT - arrival (fct is 0 until a
+        # flow completes, so the masked row-max sees only completed flows)
+        undone = _segment_sum((tb.flow_valid & ~done).astype(jnp.float32),
+                              tb.flow_lo, tb.flow_hi)
+        newly = active & (undone < 0.5)
+        last_fct = _segment_max(fct * tb.flow_valid, tb)
+        cct = jnp.where(newly, last_fct - tb.arrival, state.cct)
 
-    if not session:
-        return EngineState(coord=coord, sent=sent, done=done, fct=fct,
-                           finished=state.finished | newly, cct=cct,
-                           t0=state.t0,
-                           tick=state.tick + (n_next - tickf)
-                           .astype(jnp.int32))
-    # pending bookkeeping: cleared once the interval's horizon (or the
-    # arrival stop) is reached; (re)armed when this step's interval was
-    # truncated by the n_end cap. The anchor leaves (rate/pend_sent/
-    # pend_tick) always reflect the interval just integrated, so a
-    # re-armed pending resumes from the original evaluation instant.
-    hit = n_next >= jnp.where(resuming, stop, n_un)
-    pend_next = jnp.where(hit, jnp.float32(0.0),
-                          jnp.where(resuming, state.pend_next, n_un))
-    new = EngineState(coord=coord_new, sent=sent, done=done, fct=fct,
-                      finished=state.finished | newly, cct=cct,
-                      t0=state.t0, tick=state.tick + (n_next - tickf)
-                      .astype(jnp.int32),
-                      rate=r_use, pend_sent=anchor_sent,
-                      pend_tick=anchor_tick, pend_next=pend_next)
-    # at/past the horizon the step must be a PURE no-op: the schedule at
-    # tick n_end is evaluated on the NEXT advance, when every arrival
-    # submitted at <= n_end*δ is already in the slab — evaluating it now
-    # would bake deadlines/queues that ignore those arrivals. (`can`
-    # also pre-gated activation above, so this discarded step computed
-    # with zero admission/WC loop trips.)
-    return jax.tree_util.tree_map(
-        lambda a, b: jnp.where(can, a, b), new, state)
+        if not session:
+            return EngineState(coord=coord, sent=sent, done=done, fct=fct,
+                               finished=state.finished | newly, cct=cct,
+                               t0=state.t0,
+                               tick=state.tick + (n_next - tickf)
+                               .astype(jnp.int32)), trips
+        # pending bookkeeping: cleared once the interval's horizon (or the
+        # arrival stop) is reached; (re)armed when this step's interval was
+        # truncated by the n_end cap. The anchor leaves (rate/pend_sent/
+        # pend_tick) always reflect the interval just integrated, so a
+        # re-armed pending resumes from the original evaluation instant.
+        hit = n_next >= jnp.where(resuming, stop, n_un)
+        pend_next = jnp.where(hit, jnp.float32(0.0),
+                              jnp.where(resuming, state.pend_next, n_un))
+        new = EngineState(coord=coord_new, sent=sent, done=done, fct=fct,
+                          finished=state.finished | newly, cct=cct,
+                          t0=state.t0, tick=state.tick + (n_next - tickf)
+                          .astype(jnp.int32),
+                          rate=r_use, pend_sent=anchor_sent,
+                          pend_tick=anchor_tick, pend_next=pend_next)
+        # at/past the horizon the step must be a PURE no-op: the schedule at
+        # tick n_end is evaluated on the NEXT advance, when every arrival
+        # submitted at <= n_end*δ is already in the slab — evaluating it now
+        # would bake deadlines/queues that ignore those arrivals. (`can`
+        # also pre-gated activation above, so this discarded step computed
+        # with zero admission/WC loop trips.)
+        return jax.tree_util.tree_map(
+            lambda a, b: jnp.where(can, a, b), new, state), trips
 
 
 # ---- batched chunk runner ------------------------------------------------
@@ -541,12 +572,13 @@ def _run_chunk(state: EngineState, tb: TraceBatch, ep: EngineParams,
 
     def scan_ticks(s, tb_row, ep_row):
         def body(c, _):
-            return _tick(c, tb_row, ep_row, kernel,
+            c, _ = _tick(c, tb_row, ep_row, kernel,
                          per_flow_wc=per_flow_wc,
                          with_dynamics=with_dynamics,
                          with_ablations=with_ablations,
                          wc_maxmin=wc_maxmin,
-                         with_sampling=with_sampling), None
+                         with_sampling=with_sampling)
+            return c, None
         s, _ = jax.lax.scan(body, s, None, length=chunk)
         return s
 
@@ -815,7 +847,8 @@ def features_for(params: SchedulerParams, *, fidelity: str = "flow",
 
 
 def _session_while(state: EngineState, tb: TraceBatch, ep: EngineParams,
-                   n_end: jax.Array, max_steps: jax.Array, *,
+                   n_end: jax.Array, max_steps: jax.Array,
+                   counts: Optional[WorkCounts], *,
                    kernel: Optional[str], features: tuple):
     """The session while_loop body shared by the single-slab and the
     pmap (sharded) dispatch paths: vmapped `_tick` steps until every
@@ -823,37 +856,46 @@ def _session_while(state: EngineState, tb: TraceBatch, ep: EngineParams,
     all its real coflows. The loop condition is local to the rows it
     sees, so under `pmap` each device terminates independently — a
     shard whose lanes drain early stops stepping without waiting on
-    its neighbors."""
+    its neighbors. `counts` (per-row `WorkCounts`; zeros when None)
+    is the carry's starting value: the loop adds this dispatch's work
+    to it. Returns (state, steps, counts)."""
     (per_flow_wc, with_dynamics, with_ablations, wc_maxmin,
      with_sampling) = _norm_features(features)
+    zero = jnp.zeros(n_end.shape, jnp.int32)
+    init = WorkCounts(zero, zero, zero, zero) if counts is None else counts
 
-    def lanes_open(s):
+    def lane_open(s):
         tickf = s.tick.astype(jnp.float32)
-        done = (tickf >= n_end) | jnp.all(s.finished, axis=-1)
-        return ~jnp.all(done)
+        return ~((tickf >= n_end) | jnp.all(s.finished, axis=-1))
 
     def cond(carry):
-        s, steps = carry
-        return lanes_open(s) & (steps < max_steps)
+        s, steps, _ = carry
+        return jnp.any(lane_open(s)) & (steps < max_steps)
 
     def body(carry):
-        s, steps = carry
-        s = jax.vmap(
+        s, steps, c = carry
+        opened = lane_open(s).astype(jnp.int32)
+        s, (n_live, n_cand) = jax.vmap(
             lambda srow, tbrow, nerow, eprow: _tick(
                 srow, tbrow, eprow, kernel, per_flow_wc=per_flow_wc,
                 with_dynamics=with_dynamics,
                 with_ablations=with_ablations, wc_maxmin=wc_maxmin,
                 with_sampling=with_sampling, n_end=nerow))(
                     s, tb, n_end, ep)
-        return s, steps + 1
+        c = WorkCounts(c.event_steps + 1, c.lane_steps + opened,
+                       c.admit_trips + n_live, c.wc_trips + n_cand)
+        return s, steps + 1, c
 
-    return jax.lax.while_loop(cond, body, (state, jnp.int32(0)))
+    with jax.named_scope(SCOPE_SESSION):
+        return jax.lax.while_loop(cond, body,
+                                  (state, jnp.int32(0), init))
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "features"))
 def _run_session_block(state: EngineState, tb: TraceBatch,
                        ep: EngineParams, n_end: jax.Array,
-                       max_steps: jax.Array, *,
+                       max_steps: jax.Array,
+                       counts: Optional[WorkCounts] = None, *,
                        kernel: Optional[str], features: tuple):
     """Advance every session lane to its own `n_end` horizon (or until
     its real coflows finish) in ONE dispatch: a device-side while_loop
@@ -866,7 +908,7 @@ def _run_session_block(state: EngineState, tb: TraceBatch,
     stacks one `EngineParams` per slab row), so a heterogeneous
     multi-tenant fleet — per-row thresholds, δ, deadline factors,
     traced mechanism switches — still rides one while_loop dispatch."""
-    return _session_while(state, tb, ep, n_end, max_steps,
+    return _session_while(state, tb, ep, n_end, max_steps, counts,
                           kernel=kernel, features=features)
 
 
@@ -906,12 +948,12 @@ def _pmapped_session_block(kernel: Optional[str], features: tuple,
     1-shard pool (tests/test_pool_sharded.py)."""
     devices = list(np.asarray(mesh.devices).flat)
 
-    def block(state, tb, ep, n_end, max_steps):
-        return _session_while(state, tb, ep, n_end, max_steps,
+    def block(state, tb, ep, n_end, max_steps, counts=None):
+        return _session_while(state, tb, ep, n_end, max_steps, counts,
                               kernel=kernel, features=features)
 
     return jax.pmap(block, axis_name="rows",
-                    in_axes=(0, 0, 0, 0, None), devices=devices)
+                    in_axes=(0, 0, 0, 0, None, 0), devices=devices)
 
 
 def session_advance(state: EngineState, tb: TraceBatch, ep: EngineParams,
@@ -919,7 +961,8 @@ def session_advance(state: EngineState, tb: TraceBatch, ep: EngineParams,
                     kernel: Optional[str] = None,
                     features: tuple = (True, True, False, False, False),
                     max_steps: int = 10_000_000, mesh=None,
-                    block: bool = True):
+                    block: bool = True,
+                    counts: Optional[WorkCounts] = None):
     """Re-enter the jitted tick loop on a live session slab until every
     lane has reached its δ-grid tick target or finished all its real
     coflows. `n_end` is a scalar or a (B,) per-row array — a
@@ -942,8 +985,14 @@ def session_advance(state: EngineState, tb: TraceBatch, ep: EngineParams,
     and the DEVICE step counter is returned for the caller to fold
     into its lazy control mirror — so the caller can chain the next
     advance without waiting for this one's results.
-    Returns (state, event_steps): an int when blocking, the device
-    counter otherwise."""
+
+    `counts` (`WorkCounts` shaped like `state.tick`; zeros when None)
+    starts the per-row work counters, so a chain of dispatches handing
+    each one the last one's counters accumulates them on the device
+    (a second compiled variant: the first link of a chain passes None).
+    Returns (state, event_steps, counts): `event_steps` an int when
+    blocking, the device counter otherwise; `counts` stays on the
+    device."""
     del chunk
     ne = np.asarray(n_end, np.float32)
     if ne.shape != state.tick.shape:
@@ -953,19 +1002,20 @@ def session_advance(state: EngineState, tb: TraceBatch, ep: EngineParams,
     ne = jnp.asarray(ne.copy(), jnp.float32)
     if mesh is not None:
         fn = _pmapped_session_block(kernel, tuple(features), mesh)
-        state, steps = fn(state, tb, ep, ne, jnp.int32(max_steps))
+        state, steps, counts = fn(state, tb, ep, ne, jnp.int32(max_steps),
+                                  counts)
     else:
-        state, steps = _run_session_block(
-            state, tb, ep, ne, jnp.int32(max_steps),
+        state, steps, counts = _run_session_block(
+            state, tb, ep, ne, jnp.int32(max_steps), counts,
             kernel=kernel, features=features)
     if not block:
-        return state, steps
+        return state, steps, counts
     steps = int(np.asarray(steps).max())  # saath: lint-ok(host-pull-unaccounted): blocking mode's sanctioned sync; pool accounts the ctl read
     if steps >= max_steps:
         raise RuntimeError(
             f"session_advance exceeded {max_steps} event steps before "
             f"reaching its tick horizon (check the slab)")
-    return state, steps
+    return state, steps, counts
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "features"))
@@ -1009,7 +1059,7 @@ def session_plan_tick(state: EngineState, tb: TraceBatch,
     return jax.vmap(one)(state, tb, mask, ep)
 
 
-__all__ = ["EngineParams", "EngineState", "EngineResult",
+__all__ = ["EngineParams", "EngineState", "EngineResult", "WorkCounts",
            "default_max_ticks", "features_for", "resolve_kernel",
            "session_advance", "session_plan_tick", "scatter_rows",
            "gather_rows"]

@@ -76,6 +76,7 @@ if __name__ == "__main__":
     force_host_devices(sys.argv)
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api import Result, SessionPool, result_from_completions
 from repro.api.pool import PoolFullError
@@ -83,6 +84,13 @@ from repro.api.session import CompletedCoflow
 from repro.core.coflow import Coflow
 from repro.core.params import SchedulerParams
 from repro.launch.entry import enable_compile_cache
+
+# host spans of the front door, on the profiler's clock; the pool's own
+# spans (`repro.api.pool.SPAN_*`) nest inside `advance`
+SPAN_SUBMIT = "saath.server.submit"
+SPAN_ADVANCE = "saath.server.advance"
+SPAN_HARVEST = "saath.server.harvest"
+SPAN_ADMIT_DEFERRED = "saath.server.admit_deferred"
 
 
 class AdmissionError(RuntimeError):
@@ -316,29 +324,30 @@ class CoflowServer:
         (``policy="defer"``). Returns the handles admitted NOW (a
         deferred coflow gets its handle when a later `advance` admits
         it)."""
-        sess = self._session(tenant)
-        quota = self._quota[tenant]
-        coflows = list(coflows)
-        if quota is None:
-            handles = sess.submit(coflows)
-            self._live_bytes[tenant] += sum(c.total_bytes for c in coflows)
+        with TraceAnnotation(SPAN_SUBMIT):
+            sess = self._session(tenant)
+            quota = self._quota[tenant]
+            coflows = list(coflows)
+            if quota is None:
+                handles = sess.submit(coflows)
+                self._live_bytes[tenant] += sum(c.total_bytes for c in coflows)
+                return handles
+            agg = self._agg[tenant]
+            fits = self._budget_room(tenant, coflows)
+            if fits < len(coflows) and quota.policy == "reject":
+                agg.shed += len(coflows)
+                raise QuotaExceededError(
+                    f"tenant {tenant!r} over quota ({sess.num_live} live "
+                    f"coflows, {self._live_bytes[tenant]:.3g} live bytes); "
+                    f"batch of {len(coflows)} shed")
+            admit, overflow = coflows[:fits], coflows[fits:]
+            handles = sess.submit(admit) if admit else []
+            self._live_bytes[tenant] += sum(c.total_bytes for c in admit)
+            if overflow:
+                now = sess.now
+                self._deferred[tenant].extend((c, now) for c in overflow)
+                agg.deferred += len(overflow)
             return handles
-        agg = self._agg[tenant]
-        fits = self._budget_room(tenant, coflows)
-        if fits < len(coflows) and quota.policy == "reject":
-            agg.shed += len(coflows)
-            raise QuotaExceededError(
-                f"tenant {tenant!r} over quota ({sess.num_live} live "
-                f"coflows, {self._live_bytes[tenant]:.3g} live bytes); "
-                f"batch of {len(coflows)} shed")
-        admit, overflow = coflows[:fits], coflows[fits:]
-        handles = sess.submit(admit) if admit else []
-        self._live_bytes[tenant] += sum(c.total_bytes for c in admit)
-        if overflow:
-            now = sess.now
-            self._deferred[tenant].extend((c, now) for c in overflow)
-            agg.deferred += len(overflow)
-        return handles
 
     def _budget_room(self, tenant: str,
                      coflows: Sequence[Coflow]) -> int:
@@ -364,20 +373,21 @@ class CoflowServer:
     def _harvest(self, tenant: str) -> None:
         """Drain the session's fresh completions into the tenant's
         bounded pending buffer, folding the exact aggregates first."""
-        done = self._tenants[tenant].poll()
-        if not done:
-            return
-        agg = self._agg[tenant]
-        before = agg.bytes
-        agg.fold(done)
-        self._live_bytes[tenant] = max(
-            0.0, self._live_bytes[tenant] - (agg.bytes - before))
-        pend = self._pending[tenant]
-        pend.extend(done)
-        if len(pend) > self.history_limit:
-            drop = len(pend) - self.history_limit
-            del pend[:drop]
-            agg.trimmed += drop
+        with TraceAnnotation(SPAN_HARVEST):
+            done = self._tenants[tenant].poll()
+            if not done:
+                return
+            agg = self._agg[tenant]
+            before = agg.bytes
+            agg.fold(done)
+            self._live_bytes[tenant] = max(
+                0.0, self._live_bytes[tenant] - (agg.bytes - before))
+            pend = self._pending[tenant]
+            pend.extend(done)
+            if len(pend) > self.history_limit:
+                drop = len(pend) - self.history_limit
+                del pend[:drop]
+                agg.trimmed += drop
 
     def advance(self, dt: float) -> float:
         """Advance EVERY tenant's clock by `dt` with one pooled
@@ -387,14 +397,15 @@ class CoflowServer:
         row finished nothing since the last harvest is never polled —
         zero host work per clean tenant per step. Deferred submissions
         are then retried against the freed budget."""
-        self.pool.advance(dt)
-        fresh = {id(s) for s in self.pool.completed_sessions()}
-        if fresh:
-            for tenant, sess in self._tenants.items():
-                if id(sess) in fresh:
-                    self._harvest(tenant)
-        self._admit_deferred()
-        return dt
+        with TraceAnnotation(SPAN_ADVANCE):
+            self.pool.advance(dt)
+            fresh = {id(s) for s in self.pool.completed_sessions()}
+            if fresh:
+                for tenant, sess in self._tenants.items():
+                    if id(sess) in fresh:
+                        self._harvest(tenant)
+            self._admit_deferred()
+            return dt
 
     def _admit_deferred(self) -> None:
         """Retry each tenant's deferred queue (in deferral order):
@@ -402,26 +413,27 @@ class CoflowServer:
         longer meet their target, so admitting them only grows the
         backlog — and the rest are admitted while the freed budget
         lasts (arrivals clamp to the tenant clock on submit)."""
-        for tenant, queue in self._deferred.items():
-            if not queue:
-                continue
-            sess = self._tenants[tenant]
-            quota = self._quota[tenant]
-            agg = self._agg[tenant]
-            now = sess.now
-            keep: List[tuple] = []
-            blocked = False
-            for c, t_defer in queue:
-                if quota.slo is not None and now - t_defer > quota.slo:
-                    agg.shed += 1
+        with TraceAnnotation(SPAN_ADMIT_DEFERRED):
+            for tenant, queue in self._deferred.items():
+                if not queue:
                     continue
-                if not blocked and self._budget_room(tenant, [c]):
-                    sess.submit([c])
-                    self._live_bytes[tenant] += c.total_bytes
-                else:
-                    blocked = True    # keep the queue order: nothing
-                    keep.append((c, t_defer))  # younger jumps ahead
-            self._deferred[tenant] = keep
+                sess = self._tenants[tenant]
+                quota = self._quota[tenant]
+                agg = self._agg[tenant]
+                now = sess.now
+                keep: List[tuple] = []
+                blocked = False
+                for c, t_defer in queue:
+                    if quota.slo is not None and now - t_defer > quota.slo:
+                        agg.shed += 1
+                        continue
+                    if not blocked and self._budget_room(tenant, [c]):
+                        sess.submit([c])
+                        self._live_bytes[tenant] += c.total_bytes
+                    else:
+                        blocked = True    # keep the queue order: nothing
+                        keep.append((c, t_defer))  # younger jumps ahead
+                self._deferred[tenant] = keep
 
     def poll(self, tenant: str) -> List[CompletedCoflow]:
         """Completions for `tenant` not yet returned by a poll. This is

@@ -43,11 +43,13 @@ def _coflows(seed: int, n: int, spread: float = 2.0):
 
 
 def _run_fleet(shards: int, *, async_dispatch: bool = True, B: int = 8,
-               steps: int = 40, dt: float = 0.9, late_join: bool = True):
+               steps: int = 40, dt: float = 0.9, late_join: bool = True,
+               io: dict | None = None):
     """An adversarial fleet script: B sessions with different
     workloads, one admitted mid-run onto a recycled row, one released
     early; returns per-session completion records (handle, cct, fcts)
-    in a canonical layout for bitwise comparison."""
+    in a canonical layout for bitwise comparison. `io`, where given,
+    receives the pool's `io` counters at the end."""
     pool = SessionPool(PARAMS, num_ports=PORTS, max_sessions=B,
                        shards=shards, async_dispatch=async_dispatch)
     sessions = [pool.session() for _ in range(B)]
@@ -69,6 +71,8 @@ def _run_fleet(shards: int, *, async_dispatch: bool = True, B: int = 8,
             s.close()
     if extra is not None:
         extra.close()
+    if io is not None:
+        io.update(pool.io)
     return results
 
 
@@ -79,6 +83,22 @@ def test_sharded_pool_bitwise_equals_single_device(shards):
     got = _run_fleet(shards)
     assert got == ref, (
         f"{shards}-shard pool diverged from the single-device pool")
+
+
+@needs_devices
+@pytest.mark.parametrize("shards", [2, 4])
+def test_work_counters_equal_across_shards(shards):
+    """Each shard's loop counts its own rows' work: the pool's summed
+    counters (and the longest loop's event steps) are the 1-shard
+    pool's, async and blocking alike."""
+    work = ("event_steps", "lane_steps", "admit_trips", "wc_trips")
+    got = {}
+    for key in [(1, True), (shards, True), (shards, False)]:
+        io: dict = {}
+        _run_fleet(key[0], async_dispatch=key[1], io=io)
+        got[key] = {k: io[k] for k in work}
+    assert got[(shards, True)] == got[(1, True)] == got[(shards, False)]
+    assert got[(1, True)]["wc_trips"] > 0
 
 
 @needs_devices
