@@ -284,12 +284,12 @@ class SessionPool:
         # host<->device transfer accounting (benchmarks assert on this)
         # and the dispatches' work counters (`jax_engine.WorkCounts`,
         # added at each ctl download): event steps of the longest device
-        # loop, and per-row sums of open-lane steps, admission trips and
-        # work-conservation trips
+        # loop, and per-row sums of open-lane steps, admission trips,
+        # work-conservation candidates and the fills among them
         self.io = dict(full_uploads=0, row_uploads=0, row_downloads=0,
                        upload_bytes=0, download_bytes=0, ctl_bytes=0,
                        dispatches=0, event_steps=0, lane_steps=0,
-                       admit_trips=0, wc_trips=0)
+                       admit_trips=0, wc_trips=0, wc_fills=0)
 
     def _resolve(self, params: Optional[SchedulerParams],
                  mechanisms: Optional[dict]) -> tuple:
@@ -596,6 +596,7 @@ class SessionPool:
         self.io["lane_steps"] += int(counts.lane_steps.sum())
         self.io["admit_trips"] += int(counts.admit_trips.sum())
         self.io["wc_trips"] += int(counts.wc_trips.sum())
+        self.io["wc_fills"] += int(counts.wc_fills.sum())
         return tick_h, fin_h
 
     @_io_accounted

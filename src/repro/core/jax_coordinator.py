@@ -200,6 +200,57 @@ def _queue_of(value: jax.Array, th: jax.Array) -> jax.Array:
                             side="right").astype(jnp.int32)
 
 
+def _greedy_fill(flist: jax.Array, n_cand: jax.Array, resources):
+    """The reference's sequential greedy fill (`greedy_flow_alloc`) over
+    the priority-sorted flow list `flist`, whose first `n_cand` entries
+    are the candidates: each takes the min of its resources' residuals.
+    `resources` is a sequence of (residual vector, (F,) resource index
+    of each flow id) pairs — sender and receiver ports, plus uplink and
+    downlink on a leaf-spine fabric.
+
+    A flow given r = min(residuals) > 0 leaves that minimum at exactly 0
+    (x - x == 0 in float), and residuals never grow, so each rate
+    saturates a resource for good: at most one flow per resource gets a
+    rate. Instead of visiting every candidate (the others write 0 and
+    leave the residuals unchanged), each trip jumps to the first sorted
+    position whose resources all still have a positive residual, fills
+    it, and clears every position sharing a resource the fill
+    saturated. Trips = flows given a rate, and the rates are the full
+    walk's, bit for bit. Returns ((F,) rates by flow id, trips)."""
+    F = flist.shape[0]
+    pos = jnp.arange(F, dtype=jnp.int32)
+    ends = [idx[flist] for _, idx in resources]     # per sorted position
+    alive = pos < n_cand
+    for (avail, _), e in zip(resources, ends):
+        alive &= avail[e] > 0
+
+    def first(alive):
+        return jnp.min(jnp.where(alive, pos, F))
+
+    # a vmapped lane already done (i == F) still runs the body: its
+    # indices clamp and the loop discards its results
+    def body(s):
+        i, n, alive, avails, wcf = s
+        at = [e[i] for e in ends]
+        r = functools.reduce(jnp.minimum,
+                             [a[j] for a, j in zip(avails, at)])
+        avails = tuple(a.at[j].add(-r) for a, j in zip(avails, at))
+        # the fill saturates one of i's own resources, so i is cleared
+        # below too; clearing it here bounds the trips by the candidates
+        # whatever the residuals hold
+        alive = alive.at[i].set(False)
+        for a, e, j in zip(avails, ends, at):
+            alive &= ~((e == j) & (a[j] <= 0))
+        return (first(alive), n + 1, alive, avails,
+                wcf.at[flist[i]].set(r))
+
+    _, n_fill, _, _, wc_flow = jax.lax.while_loop(
+        lambda s: s[0] < F, body,
+        (first(alive), jnp.int32(0), alive,
+         tuple(a for a, _ in resources), jnp.zeros((F,), jnp.float32)))
+    return wc_flow, n_fill
+
+
 @functools.partial(jax.jit,
                    static_argnames=("cp", "kernel", "wc_fill"))
 def schedule_tick(state: CoordState, batch: CoflowBatch, now: jax.Array,
@@ -345,9 +396,10 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
             (jnp.int32(0), avail0, zC, jnp.zeros((C,), bool)))
 
     # D4 work conservation over the missed list (lines 18-23), gated by
-    # dp.wc via the trip count (zero iterations when the switch is off).
-    # `n_cand` counts the fill's serial trips (0 for the max-min fill,
-    # which has none).
+    # dp.wc via the candidate count (zero iterations when the switch is
+    # off). `n_cand` counts the candidates offered to the fill (0 for
+    # the max-min fill, which has no serial loop), `n_fill` those it
+    # gave a rate.
     wc_on = dp.wc > 0
     if flows is None:
         # coflow-granular fallback: one equal rate across all live flows
@@ -365,6 +417,7 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
             _, _, wc_rate = jax.lax.while_loop(
                 lambda s: s[0] < n_cand, wc_body,
                 (jnp.int32(0), avail, zC))
+            n_fill = (wc_rate > 0).sum().astype(jnp.int32)
         wc_flow = None
     else:
         # per-flow greedy fill, the reference's greedy_flow_alloc: live
@@ -372,12 +425,11 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
         # index) — exactly the reference's wc_order — each take
         # min(avail_src, avail_dst), so a strict SUBSET of a missed
         # coflow's flows can be rescued. One lexsort compacts the
-        # candidates to the front; the while_loop then walks them
-        # sequentially (trip count = candidate flows; zero when the wc
-        # switch is off). Host-A/B-tested against round-based fills
-        # with segmented scans, one-hot reductions and scatter-mins:
-        # the compacted sequential walk wins on XLA CPU — the body is
-        # two gathers + two scalar updates.
+        # candidates to the front; `_greedy_fill` then jumps from one
+        # that can still get a rate to the next. Each rate saturates a
+        # port (or link) for good, so the loop runs once per flow given
+        # a rate, at most 2P (+ 2L) trips however many candidates
+        # there are (none when the wc switch is off).
         wc_rate = zC
         avail_s, avail_r = avail[:P], avail[P:2 * P]
         F = flows.src.shape[0]
@@ -414,7 +466,7 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
                     a_send, a_recv, cand0, bw_s_ext, bw_r_ext,
                     force=kernel)
                 wc_flow = jnp.where(cand0, wc_flow, 0.0)
-            n_cand = jnp.int32(0)
+            n_cand = n_fill = jnp.int32(0)
         else:
             with jax.named_scope(SCOPE_WC_ORDER):
                 invp = jnp.argsort(perm)  # priority rank of each coflow
@@ -426,56 +478,29 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
                                      (~cand0).astype(jnp.int32)))
                 n_cand = cand0.sum().astype(jnp.int32)
 
-            if flows.up is None:
-                def wc_flow_body(s):
-                    i, a_s, a_r, wcf = s
-                    f = flist[i]
-                    sp, dq = flows.src[f], flows.dst[f]
-                    r = jnp.maximum(jnp.minimum(a_s[sp], a_r[dq]), 0.0)
-                    return (i + 1, a_s.at[sp].add(-r),
-                            a_r.at[dq].add(-r), wcf.at[f].set(r))
-
-                with jax.named_scope(SCOPE_WC_FILL):
-                    _, _, _, wc_flow = jax.lax.while_loop(
-                        lambda s: s[0] < n_cand, wc_flow_body,
-                        (jnp.int32(0), avail_s, avail_r,
-                         jnp.zeros((F,), jnp.float32)))
-            else:
+            resources = [(avail_s, flows.src), (avail_r, flows.dst)]
+            if flows.up is not None:
                 # leaf-spine: the fill is also capped by the flow's
                 # uplink/downlink residuals. Sentinel leaf id Lf
                 # indexes a BIG extra slot, so intra-leaf flows are
                 # never link-capped (and the slot absorbs their
                 # subtracts harmlessly).
                 Lf = batch.cnt_x.shape[1] // 2
-
-                def wc_flow_body(s):
-                    i, a_s, a_r, a_u, a_d, wcf = s
-                    f = flist[i]
-                    sp, dq = flows.src[f], flows.dst[f]
-                    u, d = flows.up[f], flows.dn[f]
-                    r = jnp.minimum(jnp.minimum(a_s[sp], a_r[dq]),
-                                    jnp.minimum(a_u[u], a_d[d]))
-                    r = jnp.maximum(r, 0.0)
-                    return (i + 1, a_s.at[sp].add(-r),
-                            a_r.at[dq].add(-r), a_u.at[u].add(-r),
-                            a_d.at[d].add(-r), wcf.at[f].set(r))
-
-                with jax.named_scope(SCOPE_WC_FILL):
-                    a_u0 = jnp.concatenate([avail[2 * P:2 * P + Lf],
-                                            BIG[None]])
-                    a_d0 = jnp.concatenate([avail[2 * P + Lf:],
-                                            BIG[None]])
-                    _, _, _, _, _, wc_flow = jax.lax.while_loop(
-                        lambda s: s[0] < n_cand, wc_flow_body,
-                        (jnp.int32(0), avail_s, avail_r, a_u0, a_d0,
-                         jnp.zeros((F,), jnp.float32)))
+                resources += [
+                    (jnp.concatenate([avail[2 * P:2 * P + Lf],
+                                      BIG[None]]), flows.up),
+                    (jnp.concatenate([avail[2 * P + Lf:], BIG[None]]),
+                     flows.dn)]
+            with jax.named_scope(SCOPE_WC_FILL):
+                wc_flow, n_fill = _greedy_fill(flist, n_cand, resources)
 
     new_state = CoordState(queue=jnp.where(act, q, state.queue),
                            deadline=deadline, running=admitted)
-    # n_live / n_cand: the admission and work-conservation loops' trip
-    # counts this tick (the work counters `jax_engine` sums per row)
+    # n_live / n_cand / n_fill: the admission loop's trips, the
+    # work-conservation fill's candidates and the ones it gave a rate
+    # this tick (the work counters `jax_engine` sums per row)
     out = {"rate": rate, "wc_rate": wc_rate, "wc_flow": wc_flow,
            "admitted": admitted, "queue": q, "contention": k,
            "expired": expired, "order": perm,
-           "n_live": n_live, "n_cand": n_cand}
+           "n_live": n_live, "n_cand": n_cand, "n_fill": n_fill}
     return new_state, out

@@ -149,13 +149,17 @@ class WorkCounts(NamedTuple):
     - `lane_steps`: iterations in which the row's lane was open (short
       of its horizon with unfinished coflows);
     - `admit_trips`: admission while_loop trips (live coflows per tick);
-    - `wc_trips`: work-conservation fill trips (candidate flows per
-      tick for the per-flow fill, live coflows for the coflow-granular
-      one, 0 for the max-min fill)."""
+    - `wc_trips`: candidates offered to the work-conservation fill
+      (candidate flows per tick for the per-flow greedy fill, live
+      coflows for the coflow-granular one, 0 for the max-min fill);
+    - `wc_fills`: the candidates the fill gave a rate (the per-flow
+      greedy fill's loop trips, at most 2P (+ 2L) a tick; 0 for the
+      max-min fill)."""
     event_steps: jax.Array
     lane_steps: jax.Array
     admit_trips: jax.Array
     wc_trips: jax.Array
+    wc_fills: jax.Array
 
 
 # ---- single-trace tick ---------------------------------------------------
@@ -379,8 +383,9 @@ def _tick(state: EngineState, tb: TraceBatch, ep: EngineParams,
     so incremental replay is bitwise the offline scan. `None` (offline
     replay) compiles both the cap and the pending machinery out.
 
-    Returns (new state, (admission trips, work-conservation trips)) of
-    the step's coordinator tick (`tick_core`'s loop trip counts).
+    Returns (new state, (admission trips, work-conservation candidates,
+    work-conservation fills)) of the step's coordinator tick
+    (`tick_core`'s work counts).
     """
     session = n_end is not None
     delta = ep.delta
@@ -397,7 +402,7 @@ def _tick(state: EngineState, tb: TraceBatch, ep: EngineParams,
     coord, out = jc.tick_core(state.coord, batch, now, ep.dp,
                               kernel=kernel, flows=flows,
                               wc_fill="maxmin" if wc_maxmin else "greedy")
-    trips = (out["n_live"], out["n_cand"])
+    trips = (out["n_live"], out["n_cand"], out["n_fill"])
     with jax.named_scope(SCOPE_HORIZON):
         # per-flow rates: MADD equal rate for admitted coflows + the work-
         # conservation fill (flow-granular when per_flow_wc, else the
@@ -862,7 +867,8 @@ def _session_while(state: EngineState, tb: TraceBatch, ep: EngineParams,
     (per_flow_wc, with_dynamics, with_ablations, wc_maxmin,
      with_sampling) = _norm_features(features)
     zero = jnp.zeros(n_end.shape, jnp.int32)
-    init = WorkCounts(zero, zero, zero, zero) if counts is None else counts
+    init = WorkCounts(*[zero] * len(WorkCounts._fields)) \
+        if counts is None else counts
 
     def lane_open(s):
         tickf = s.tick.astype(jnp.float32)
@@ -875,7 +881,7 @@ def _session_while(state: EngineState, tb: TraceBatch, ep: EngineParams,
     def body(carry):
         s, steps, c = carry
         opened = lane_open(s).astype(jnp.int32)
-        s, (n_live, n_cand) = jax.vmap(
+        s, (n_live, n_cand, n_fill) = jax.vmap(
             lambda srow, tbrow, nerow, eprow: _tick(
                 srow, tbrow, eprow, kernel, per_flow_wc=per_flow_wc,
                 with_dynamics=with_dynamics,
@@ -883,7 +889,8 @@ def _session_while(state: EngineState, tb: TraceBatch, ep: EngineParams,
                 with_sampling=with_sampling, n_end=nerow))(
                     s, tb, n_end, ep)
         c = WorkCounts(c.event_steps + 1, c.lane_steps + opened,
-                       c.admit_trips + n_live, c.wc_trips + n_cand)
+                       c.admit_trips + n_live, c.wc_trips + n_cand,
+                       c.wc_fills + n_fill)
         return s, steps + 1, c
 
     with jax.named_scope(SCOPE_SESSION):

@@ -315,9 +315,9 @@ def test_pool_async_ctl_download_charged_once_at_sync_point():
     assert pool.io["dispatches"] == base_disp + 5
     assert pool.io["ctl_bytes"] == base_ctl, \
         "async dispatch paid a ctl download at dispatch time"
-    # the tick and completion mirrors, and the four per-row int32 work
+    # the tick and completion mirrors, and the five per-row int32 work
     # counters (`jax_engine.WorkCounts`) that ride the same download
-    expect = pool._ticks.nbytes + pool._fin.nbytes + 4 * pool._ticks.nbytes
+    expect = pool._ticks.nbytes + pool._fin.nbytes + 5 * pool._ticks.nbytes
     assert a.poll() == []                   # the sync point
     assert pool._ctl is None                # handle consumed
     assert pool.io["ctl_bytes"] == base_ctl + expect, \

@@ -91,7 +91,8 @@ def test_work_counters_equal_across_shards(shards):
     """Each shard's loop counts its own rows' work: the pool's summed
     counters (and the longest loop's event steps) are the 1-shard
     pool's, async and blocking alike."""
-    work = ("event_steps", "lane_steps", "admit_trips", "wc_trips")
+    work = ("event_steps", "lane_steps", "admit_trips", "wc_trips",
+            "wc_fills")
     got = {}
     for key in [(1, True), (shards, True), (shards, False)]:
         io: dict = {}
@@ -99,6 +100,7 @@ def test_work_counters_equal_across_shards(shards):
         got[key] = {k: io[k] for k in work}
     assert got[(shards, True)] == got[(1, True)] == got[(shards, False)]
     assert got[(1, True)]["wc_trips"] > 0
+    assert 0 < got[(1, True)]["wc_fills"] <= got[(1, True)]["wc_trips"]
 
 
 @needs_devices

@@ -20,7 +20,8 @@ from repro.launch import serve
 PORTS = 6
 PARAMS = SchedulerParams(port_bw=1.0, delta=1e-2, start_threshold=4.0,
                          growth=4.0, num_queues=5)
-WORK = ("event_steps", "lane_steps", "admit_trips", "wc_trips")
+WORK = ("event_steps", "lane_steps", "admit_trips", "wc_trips",
+        "wc_fills")
 
 
 def _work(pool) -> dict:
@@ -32,7 +33,8 @@ def _two_tenants(async_dispatch: bool):
     3->4) both want sender port 0 at t=0. Coflow 0 leads the order (same
     queue and contention, earlier arrival) and takes port 0 whole, so
     coflow 1 is missed: 2 admission trips, and its 2 live flows are the
-    work-conservation candidates (2 trips). Tenant b: one coflow (one
+    work-conservation candidates (2 trips). Flow 0->2 finds port 0
+    exhausted; flow 3->4 gets a rate (1 fill). Tenant b: one coflow (one
     flow 5->0), admitted: 1 admission trip, no candidate. Flows of 500
     bytes at 1 byte/s finish in no early tick."""
     pool = SessionPool(PARAMS, num_ports=PORTS, max_sessions=2,
@@ -52,7 +54,7 @@ def test_work_counters_exact_on_a_hand_built_two_tenant_pool(
     pool.advance(PARAMS.delta)              # one tick: one event step
     assert a.poll() == [] and b.poll() == []
     assert _work(pool) == dict(event_steps=1, lane_steps=2,
-                               admit_trips=3, wc_trips=2)
+                               admit_trips=3, wc_trips=2, wc_fills=1)
     snap = a.snapshot()
     assert [snap[h]["running"] for h in sorted(snap)] == [True, False]
 
@@ -83,6 +85,7 @@ def test_work_counters_identical_between_blocking_and_async():
     got, blocking = run(True), run(False)
     assert got == blocking
     assert got[0]["event_steps"] > 0 and got[0]["wc_trips"] > 0
+    assert 0 < got[0]["wc_fills"] <= got[0]["wc_trips"]
 
 
 def test_host_spans_nest_on_the_profiler_clock(tmp_path):
