@@ -1,7 +1,10 @@
 """The system under test, driven the way a coordinator's client drives it.
 
 `Fleet` builds one `repro.launch.serve.CoflowServer` for a configuration
-(one row per tenant of one shared slab) and moves it in rounds: each
+(one row per tenant of one shared slab, its rows sharded over the
+cell's chips; a big switch, or the configuration's `topology`, e.g.
+`{"kind": "leaf_spine", "hosts_per_leaf": 4, "oversub": 4.0,
+"wc_fill": "maxmin"}`) and moves it in rounds: each
 round submits every tenant's coflows that arrive within the next δ of
 virtual time, calls `advance(δ)` (which returns once the pool's control
 download is on the host) and polls every tenant's completions. Rounds
@@ -24,8 +27,24 @@ def _annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+def topology(config: dict):
+    """The program's fabric model for the configuration's `topology`
+    entry; None (a big switch) where it has none."""
+    spec = config.get("topology")
+    if spec is None:
+        return None
+    if spec["kind"] != "leaf_spine":
+        raise ValueError(f"unknown topology kind {spec['kind']!r}")
+    from repro.fabric.topology import LeafSpine
+
+    return LeafSpine(hosts_per_leaf=int(spec["hosts_per_leaf"]),
+                     oversub=float(spec["oversub"]),
+                     wc_fill=spec["wc_fill"])
+
+
 class Fleet:
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    def __init__(self, config: dict, traffic: dict, seed: int,
+                 shards: int = 1):
         from repro.core.params import SchedulerParams
         from repro.launch.serve import CoflowServer
 
@@ -33,8 +52,19 @@ class Fleet:
         self.params = SchedulerParams(**config["params"])
         self.delta = self.params.delta
         self.n = int(config["tenants"])
+        if self.n % shards:
+            raise ValueError(
+                f"{self.n} tenants cannot be sharded evenly over {shards} "
+                f"chips: make the configuration's tenants a multiple of "
+                f"the cell's chips")
+        # a one-chip big switch gets exactly the arguments it always had
+        extra = {}
+        if shards > 1:
+            extra["shards"] = shards
+        if "topology" in config:
+            extra["topology"] = topology(config)
         self.srv = CoflowServer(self.params, num_ports=config["num_ports"],
-                                max_tenants=self.n)
+                                max_tenants=self.n, **extra)
         self.names = [f"tenant{i}" for i in range(self.n)]
         for name in self.names:
             self.srv.register(name)
@@ -120,9 +150,10 @@ class Fleet:
 
 
 def offered_load(fleet: Fleet, horizon: float) -> float:
-    """Bytes submitted per second over the fabric's capacity, summed
-    over tenants (the realized load of the stream up to `horizon`)."""
-    cap = fleet.config["num_ports"] * fleet.params.port_bw * horizon
+    """Bytes submitted per second over the fabric's capacity, the mean
+    over tenants (the realized load of the streams up to `horizon`)."""
+    cap = (fleet.n * fleet.config["num_ports"] * fleet.params.port_bw
+           * horizon)
     total = sum(s.total_bytes for sub in fleet.submitted for s in sub)
     return total / cap if cap else float("nan")
 

@@ -65,7 +65,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              rounds: int | None = None) -> dict:
     """A whole run; returns the result object and prints the earlier
     lines. `t_start` is when the process started (set-up's origin).
-    `keep`, where given, receives what the comparison was made from;
+    `keep`, where given, receives what the comparison was made from
+    (the window's completions among it);
     `rounds`, where given, ends the window after that many rounds
     instead of after `seconds` (the tests' fixed amount of work)."""
     import jax
@@ -75,7 +76,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     cfg, traffic = cell.config, cell.traffic
     steps = [float(x) for x in cfg["fast_forward_s"]]
     with ops.record_paths() as paths:
-        fl = fleet_mod.Fleet(cfg, traffic, seed)
+        # the pool's rows are sharded over the cell's own chips
+        fl = fleet_mod.Fleet(cfg, traffic, seed, shards=len(devices))
         t0 = time.perf_counter()
         fl.fast_forward(steps)
         t1 = time.perf_counter()
@@ -198,7 +200,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     chk = compare.checks(cfg, vals)
     if keep is not None:
         keep.update(submitted=submitted, t0=t_w0_virtual, t1=t_end,
-                    readings=vals)
+                    served=served_window, readings=vals)
     log(f"reference: {len(submitted)} tenants, "
         f"{time.perf_counter() - r0:.3f}s; "
         f"avg_cct_rel_err {vals['avg_cct_rel_err']}")

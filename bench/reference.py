@@ -1,12 +1,19 @@
 """The plain reference: Saath's coordinator as a float64 numpy simulator.
 
 A copy of the program's numpy plane (`repro.core.policies.saath`,
-`repro.fabric.engine`, `repro.core.queues`, `repro.core.contention`),
-cut to what the benchmark's configuration states: clairvoyant Saath on
-a big-switch fabric with all-or-none admission, per-flow queue
-thresholds, LCoF order, starvation deadlines, the §4.3 re-queue, and
-work conservation by the paper's greedy per-flow walk. It imports nothing of the program, so no later change to the program can
-move the yardstick.
+`repro.core.policies.base`, `repro.fabric.engine`,
+`repro.fabric.topology`, `repro.core.queues`, `repro.core.contention`),
+cut to what the benchmark's configurations state: clairvoyant Saath
+with all-or-none admission, per-flow queue thresholds, LCoF order,
+starvation deadlines, the §4.3 re-queue, and work conservation by the
+paper's greedy per-flow walk. The fabric is a big switch, or a
+leaf-spine (a configuration's `topology`): each leaf's uplink and
+downlink carry the leaf's port bandwidth over `oversub`, an inter-leaf
+flow crosses its source leaf's uplink and its destination leaf's
+downlink, admission and the greedy walk check those links beside the
+ports, and `wc_fill: "maxmin"` fills the leftover by max-min
+water-filling instead of the walk. It imports nothing of the program,
+so no later change to the program can move the yardstick.
 
 The schedule is recomputed on the δ grid at events (an arrival, a flow
 completion, a queue-threshold crossing, a deadline), rates are constant
@@ -49,10 +56,33 @@ class Params:
         return self._th
 
 
+class Links:
+    """A leaf-spine fabric's leaf links: `cap[k]` is leaf k's uplink
+    for k < Lf and leaf k - Lf's downlink above, `up[f]` and `dn[f]`
+    flow f's uplink and downlink ids into `cap`, both -1 where the flow
+    stays inside its leaf. `maxmin` selects the max-min fill."""
+
+    def __init__(self, topology: dict, src, dst, bw):
+        if topology["kind"] != "leaf_spine":
+            raise ValueError(f"unknown topology kind {topology['kind']!r}")
+        h = int(topology["hosts_per_leaf"])
+        P = bw.shape[0]
+        Lf = -(-P // h)
+        leaf = np.arange(P) // h
+        per_leaf = np.bincount(leaf, weights=bw, minlength=Lf) \
+            / float(topology["oversub"])
+        self.cap = np.concatenate([per_leaf, per_leaf])
+        inter = src // h != dst // h
+        self.up = np.where(inter, src // h, -1)
+        self.dn = np.where(inter, dst // h + Lf, -1)
+        self.maxmin = topology["wc_fill"] == "maxmin"
+
+
 class Table:
     """All flows of the submitted coflows, flattened per coflow."""
 
-    def __init__(self, coflows, num_ports: int, port_bw: float):
+    def __init__(self, coflows, num_ports: int, port_bw: float,
+                 topology: Optional[dict] = None):
         C = len(coflows)
         self.num_ports = P = num_ports
         self.C = C
@@ -74,6 +104,8 @@ class Table:
         self.finished = np.zeros(C, bool)
         self.cct = np.full(C, np.nan)
         self.bw = np.full(P, port_bw)
+        self.links = None if topology is None else Links(
+            topology, self.src, self.dst, self.bw)
 
     def flow_live(self):
         return self.active[self.cid] & ~self.done
@@ -101,11 +133,16 @@ def contention(A_s, A_r, active) -> np.ndarray:
     return np.where(active, k, 0)
 
 
-def greedy_fill(t: Table, order, live, avail_s, avail_r, rates) -> None:
+def greedy_fill(t: Table, order, live, avail_s, avail_r, rates,
+                avail_x=None) -> None:
     """The paper's D4 walk: each flow in order takes the least residual
     of its two ports, as a round-based walk in which each round
     allocates every flow that is the first in order on both its ports
-    (the one-at-a-time result exactly)."""
+    (the one-at-a-time result exactly). On a leaf-spine fabric its
+    links count as two more resources."""
+    if t.links is not None:
+        return _greedy_fill_links(t, order, live, avail_s, avail_r,
+                                  avail_x, rates)
     src, dst = t.src, t.dst
     ordered = order[live[order]]
     for _ in range(2 * t.num_ports + 2):
@@ -126,6 +163,98 @@ def greedy_fill(t: Table, order, live, avail_s, avail_r, rates) -> None:
         avail_s[src[take]] -= r
         avail_r[dst[take]] -= r
         ordered = cand[~first]
+
+
+def _greedy_fill_links(t: Table, order, live, avail_s, avail_r, avail_x,
+                       rates) -> None:
+    """The D4 walk over ports and leaf links: a flow is taken in the
+    round in which it is the first in order on its two ports and on
+    each link it crosses (an intra-leaf flow on none), at the least
+    residual of them all."""
+    src, dst, up, dn = t.src, t.dst, t.links.up, t.links.dn
+    Lx = avail_x.shape[0]
+    ordered = order[live[order]]
+    for _ in range(2 * (t.num_ports + Lx) + 2):
+        if ordered.size == 0:
+            break
+        u, d = up[ordered], dn[ordered]
+        ok = (avail_s[src[ordered]] > 0.0) & (avail_r[dst[ordered]] > 0.0)
+        ok &= (u < 0) | (avail_x[np.maximum(u, 0)] > 0.0)
+        ok &= (d < 0) | (avail_x[np.maximum(d, 0)] > 0.0)
+        cand = ordered[ok]
+        if cand.size == 0:
+            break
+        # an intra-leaf flow gets an id of its own on each link axis, so
+        # that it is the first there
+        fresh = Lx + np.arange(cand.size)
+        first = np.ones(cand.size, bool)
+        for k in (src[cand], dst[cand], np.where(up[cand] >= 0, up[cand],
+                                                 fresh),
+                  np.where(dn[cand] >= 0, dn[cand], fresh)):
+            f = np.zeros(cand.size, bool)
+            f[np.unique(k, return_index=True)[1]] = True
+            first &= f
+        take = cand[first]
+        r = np.minimum(avail_s[src[take]], avail_r[dst[take]])
+        tu, td = up[take], dn[take]
+        mu, md = tu >= 0, td >= 0
+        r = np.minimum(r, np.where(mu, avail_x[np.maximum(tu, 0)], np.inf))
+        r = np.minimum(r, np.where(md, avail_x[np.maximum(td, 0)], np.inf))
+        rates[take] = r
+        avail_s[src[take]] -= r
+        avail_r[dst[take]] -= r
+        avail_x[tu[mu]] -= r[mu]
+        avail_x[td[md]] -= r[md]
+        ordered = cand[~first]
+
+
+def maxmin_fill(t: Table, cand, avail_s, avail_r, avail_x) -> np.ndarray:
+    """Max-min fair rates for the flows in `cand` over the residual
+    port and link capacities (updated in place), by progressive
+    filling: each round raises every unfrozen flow to the least fair
+    level of a port or link and freezes the flows of those that it
+    saturates."""
+    src, dst, up, dn = t.src, t.dst, t.links.up, t.links.dn
+    P, Lx = t.num_ports, avail_x.shape[0]
+    up_ok, dn_ok = up >= 0, dn >= 0
+    rates = np.zeros(t.size.shape[0])
+    frozen = ~cand
+    for _ in range(2 * (P + Lx) + 2):
+        if frozen.all():
+            break
+        act = ~frozen
+        cnt_s = np.bincount(src[act], minlength=P)
+        cnt_r = np.bincount(dst[act], minlength=P)
+        cnt_x = (np.bincount(up[act & up_ok], minlength=Lx)
+                 + np.bincount(dn[act & dn_ok], minlength=Lx))
+        with np.errstate(divide="ignore", invalid="ignore"):
+            lvl_s = np.where(cnt_s > 0, avail_s / np.maximum(cnt_s, 1),
+                             np.inf)
+            lvl_r = np.where(cnt_r > 0, avail_r / np.maximum(cnt_r, 1),
+                             np.inf)
+            lvl_x = np.where(cnt_x > 0, avail_x / np.maximum(cnt_x, 1),
+                             np.inf)
+        lvl = min(min(lvl_s.min(), lvl_r.min()), lvl_x.min())
+        if not np.isfinite(lvl):
+            break
+        sat_s = (lvl_s <= lvl + 1e-12) & (cnt_s > 0)
+        sat_r = (lvl_r <= lvl + 1e-12) & (cnt_r > 0)
+        sat_x = (lvl_x <= lvl + 1e-12) & (cnt_x > 0)
+        hit = act & (sat_s[src] | sat_r[dst])
+        hit |= act & ((up_ok & sat_x[np.maximum(up, 0)])
+                      | (dn_ok & sat_x[np.maximum(dn, 0)]))
+        if not hit.any():
+            break
+        rates[hit] = lvl
+        np.subtract.at(avail_s, src[hit], lvl)
+        np.subtract.at(avail_r, dst[hit], lvl)
+        np.maximum(avail_s, 0.0, out=avail_s)
+        np.maximum(avail_r, 0.0, out=avail_r)
+        np.subtract.at(avail_x, up[hit & up_ok], lvl)
+        np.subtract.at(avail_x, dn[hit & dn_ok], lvl)
+        np.maximum(avail_x, 0.0, out=avail_x)
+        frozen |= hit
+    return rates
 
 
 class Saath:
@@ -211,6 +340,16 @@ class Saath:
         np.add.at(cnt_s, (lc, ls), 1)
         np.add.at(cnt_r, (lc, ld), 1)
         avail_s, avail_r = t.bw.copy(), t.bw.copy()
+        links = t.links
+        avail_x = cnt_x = None
+        if links is not None:
+            # each inter-leaf flow counts once on its uplink and once
+            # on its downlink
+            avail_x = links.cap.copy()
+            cnt_x = np.zeros((act.size, avail_x.shape[0]), np.int64)
+            lf = live & (links.up >= 0)
+            np.add.at(cnt_x, (loc[t.cid[lf]], links.up[lf]), 1)
+            np.add.at(cnt_x, (loc[t.cid[lf]], links.dn[lf]), 1)
         admitted = np.zeros(t.C, bool)
         missed = []
         for c in order:
@@ -224,6 +363,11 @@ class Saath:
                 r = min(r, (avail_s[ps] / cs[ps]).min())
             if pr.any():
                 r = min(r, (avail_r[pr] / cr[pr]).min())
+            if links is not None:
+                cx = cnt_x[j]
+                px = cx > 0
+                if px.any():
+                    r = min(r, (avail_x[px] / cx[px]).min())
             if r < p.min_rate or r <= 0.0:
                 missed.append(c)
                 continue
@@ -231,12 +375,21 @@ class Saath:
             rates[lo:hi][live[lo:hi]] = r
             avail_s -= r * cs
             avail_r -= r * cr
+            if links is not None:
+                avail_x -= r * cnt_x[j]
             admitted[c] = True
 
         if p.work_conservation and missed:
             order_f = np.concatenate([np.arange(t.flow_lo[c], t.flow_hi[c])
                                       for c in missed])
-            greedy_fill(t, order_f, live, avail_s, avail_r, rates)
+            if links is not None and links.maxmin:
+                cand = np.zeros(live.shape, bool)
+                cand[order_f] = True
+                rates += maxmin_fill(t, cand & live, avail_s, avail_r,
+                                     avail_x)
+            else:
+                greedy_fill(t, order_f, live, avail_s, avail_r, rates,
+                            avail_x)
         self.running = admitted
         return rates
 
@@ -281,17 +434,19 @@ def _integrate(t: Table, rates, live, now: float, t_next: float) -> None:
 
 
 def run(coflows, params: dict, num_ports: int, *,
-        until: float, work_conservation: Optional[bool] = None
-        ) -> np.ndarray:
+        until: float, work_conservation: Optional[bool] = None,
+        topology: Optional[dict] = None) -> np.ndarray:
     """Replay `coflows` (objects with arrival, src, dst, size) until the
     first δ tick at or past `until`; returns each coflow's CCT (NaN if
-    it had not finished). `work_conservation=False` switches D4 off."""
+    it had not finished). `work_conservation=False` switches D4 off;
+    `topology` is a configuration's leaf-spine entry (None: a big
+    switch)."""
     if not coflows:
         return np.zeros(0)
     p = Params(**params)
     if work_conservation is not None:
         p.work_conservation = work_conservation
-    t = Table(coflows, num_ports, p.port_bw)
+    t = Table(coflows, num_ports, p.port_bw, topology)
     pol = Saath(p, t)
     arrivals = np.unique(t.arrival)
     now = _up(float(arrivals[0]), p.delta)

@@ -1,7 +1,7 @@
 """Operations and bytes of the scheduler's kernels, from the logical
 operands each call receives (per lane, before any padding the kernel
-adds), as the plain reference `repro.kernels.ref.contention_ref`
-computes them. A later change that drops padding or
+adds), as the plain references `repro.kernels.ref.contention_ref` and
+`maxmin_ref` compute them. A later change that drops padding or
 replaces a kernel is read against the same work."""
 from __future__ import annotations
 
@@ -31,15 +31,33 @@ def contention_work(C: int, P: int) -> tuple:
     return flops, nbytes
 
 
-WORK = {"contention": contention_work}
+def maxmin_work(P: int, F: int) -> tuple:
+    """(flops, bytes) of maxmin_ref on (P, F) f32 one-hot incidence
+    (ports, or ports then leaf links, by flows): 2P + 2 rounds, each
+    with six P x F mat-vec products (the two active counts, the two
+    saturated-row spreads, the two residual updates), 8F flow-wise
+    (active mask of two, hit test of four, rate select, freeze) and
+    22P row-wise operations (two levels of four, two minima, two
+    saturation tests of three, two residual updates of three); reads
+    both one-hots, the live mask and both bandwidths, writes F f32
+    rates."""
+    rounds = 2 * P + 2
+    flops = rounds * (6 * 2 * P * F + 8 * F + 22 * P)
+    nbytes = 2 * P * F * F32 + F + 2 * P * F32 + F * F32
+    return flops, nbytes
+
+
+WORK = {"contention": contention_work, "maxmin": maxmin_work}
 
 
 def share(ctx, op: str, match: str):
     """A kernel's share of its roofline in the trace, in percent: the
     least time its calls need at the chip's peaks (the larger of flops
     over peak FLOP/s and bytes over peak bandwidth, summed over calls,
-    each call covering every lane of the slab), over the kernel's device
-    time. None where the trace has no call of the kernel."""
+    each call covering the lanes of one chip's rows: all of the slab's,
+    over the number of chips its rows are sharded on), over the kernel's
+    device time, summed over chips. None where the trace has no call of
+    the kernel."""
     evs = ctx.kernel_events(match)
     shape = ctx.kernel_shapes.get(op)
     if not evs or shape is None:
@@ -48,4 +66,4 @@ def share(ctx, op: str, match: str):
     flops, nbytes = WORK[op](*shape)
     least = max(flops / pk["flops_per_s"], nbytes / pk["hbm_bytes_per_s"])
     t = sum(e - s for _, _, s, e in evs)
-    return 100.0 * least * ctx.lanes * len(evs) / t
+    return 100.0 * least * (ctx.lanes / ctx.n_devices) * len(evs) / t

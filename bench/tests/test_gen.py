@@ -89,3 +89,22 @@ def test_flows_keep_the_coflow_total_and_the_width_cap():
                 & (c.dst < P)).all()
     single = np.mean([len(c.size) == 1 for c in cfs])
     assert 0.1 < single < 0.4
+
+
+def test_every_seed_offers_each_tenant_the_mix_load():
+    """Each tenant's stream runs at the mix's load, at every seed: the
+    offered bytes over its rate sample are that load of the fabric."""
+    cfg = dict(_cfg("fb150"), tenants=4, num_ports=24)
+    rates = None
+    for seed in (3, 2**31 + 99):
+        ss = gen.streams(cfg, _traffic("steady"), seed)
+        assert rates is None or [s.rate for s in ss] == rates
+        rates = [s.rate for s in ss]
+    for s in ss:
+        cfs = []
+        while len(cfs) < gen.RATE_COFLOWS:
+            cfs += s.until(s._t + 1.0)
+        cfs = cfs[:gen.RATE_COFLOWS]
+        got = sum(c.total_bytes for c in cfs) / (
+            cfs[-1].arrival * 24 * cfg["params"]["port_bw"])
+        assert got == pytest.approx(0.9, rel=0.1)
