@@ -24,7 +24,7 @@ This package makes those contracts machine-checked:
   `accounted_transfer` carve-outs for the pool's io-counted paths).
 * ``repro.analysis.coherence`` — the slab coherence checker: the
   async serving plane's cache protocol (dirty flags, deferred ctl
-  handle, host mirrors, folded dispatch caches, the io ledger) as a
+  handle, host mirrors, the folded parameter copy, the io ledger) as a
   machine-readable declaration, typestate-checked per method against
   the committed golden ``analysis/coherence_manifest.json``
   (``make coherence`` / ``make coherence-update``); ``--selftest``

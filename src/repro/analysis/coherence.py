@@ -26,8 +26,8 @@ machine-checked, in three layers:
    - `coh-sync-before-mirror` every ctl-mirror access is dominated by
                              `_sync_ctl()` (directly or via a callee
                              that provides it on every exit)
-   - `coh-stale-folded-cache` every `_tb` / `_ep_stack` rewrite also
-                             touches its folded dispatch cache
+   - `coh-stale-folded-cache` every `_ep_stack` rewrite also touches
+                             its folded dispatch copy
    - `coh-ctl-consume-once`  the deferred async ctl handle is armed in
                              one place, consumed exactly once
    - `coh-unaccounted-transfer` no public pool method reaches a
@@ -90,12 +90,11 @@ RULES = {
 
 PROTOCOL: Dict[str, Dict[str, str]] = {
     "SessionPool": {
-        "_tb": "device TraceBatch slab (row-major, padded)",
+        "_tb": "device TraceBatch slab (row-major, padded; folded "
+               "when sharded)",
         "_state": "device EngineState/CoordState slab (folded when "
                   "sharded)",
-        "_tb_disp": "folded per-shard dispatch view of _tb; None "
-                    "means stale",
-        "_ep_disp": "folded per-shard dispatch view of the "
+        "_ep_disp": "folded per-shard dispatch copy of the "
                     "EngineParams stack; None means stale",
         "_ep_stack": "stacked per-row EngineParams; None means stale",
         "_ticks": "lazy host mirror of per-row device tick counters",
@@ -158,7 +157,7 @@ SYNC_VARS = frozenset({"_ticks", "_fin", "_fresh", "_new_done"})
 # membership vars whose mutation requires _tb_dirty on every exit
 MEMBERSHIP_VARS = frozenset({"_live", "_slots"})
 # slab source -> folded dispatch cache it must invalidate
-CACHE_OF = {"_tb": "_tb_disp", "_ep_stack": "_ep_disp"}
+CACHE_OF = {"_ep_stack": "_ep_disp"}
 
 _MUTATORS = frozenset({
     "add", "append", "clear", "discard", "extend", "insert", "pop",
@@ -166,7 +165,7 @@ _MUTATORS = frozenset({
 })
 _TRANSFER_LEAVES = frozenset({
     "scatter_rows", "gather_rows", "session_advance",
-    "session_plan_tick", "device_put",
+    "session_plan_tick", "scatter", "gather", "plan_tick", "device_put",
 })
 
 FILES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
@@ -951,9 +950,9 @@ SEEDED_MUTATIONS: Tuple[Tuple[str, str, str, str, str], ...] = (
      "        if completions_only and not self._fresh:",
      R_SYNC),
     ("stale-folded-cache", "api/pool.py",
-     "            self._tb = self._place(self._tb)\n"
-     "            self._tb_disp = None",
-     "            self._tb = self._place(self._tb)",
+     "                lambda *xs: jnp.stack(xs), *rows)\n"
+     "            self._ep_disp = None",
+     "                lambda *xs: jnp.stack(xs), *rows)",
      R_CACHE),
     ("double-consumed-ctl-handle", "api/pool.py",
      "        tick_dev, fin_dev, counts, n = self._ctl\n"

@@ -65,11 +65,10 @@ TRACE_CONSUMERS = {"scan", "while_loop", "cond", "switch", "fori_loop",
 _META_ATTRS = {"shape", "dtype", "ndim", "weak_type", "sharding",
                "aval", "nbytes", "itemsize"}
 # device attrs / device-returning calls of the io-counted pool class
-_POOL_DEVICE_ATTRS = {"_state", "_tb", "_ctl", "_tb_disp", "_ep_disp",
-                      "_ep_stack"}
-_POOL_DEVICE_CALLS = {"_state_flat", "_dispatch_slab", "gather_rows",
-                      "scatter_rows", "session_advance",
-                      "session_plan_tick"}
+_POOL_DEVICE_ATTRS = {"_state", "_tb", "_ctl", "_ep_disp", "_ep_stack"}
+_POOL_DEVICE_CALLS = {"_dispatch_slab", "gather_rows", "scatter_rows",
+                      "session_advance", "session_plan_tick",
+                      "scatter", "gather", "plan_tick"}
 _ENGINE_DEVICE_CALLS = {"_run_session_block", "_pmapped_session_block"}
 _PULL_FUNCS = {"asarray", "array", "device_get"}
 _NP_ROOTS = {"np", "numpy"}

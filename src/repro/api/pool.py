@@ -68,12 +68,14 @@ so each device runs its OWN while_loop over its rows and terminates
 independently (pmap compiles the exact single-slab program per
 device — no GSPMD partitioner, hence no partitioner-inserted
 collectives, which would deadlock divergent per-shard loops on the
-CPU backend). The dirty-row scatter stage keeps ONE QUEUE PER SHARD
-(a dirty row only funnels an update through its owning shard). Rows
-are independent sessions — there is no cross-shard communication
-inside the loop — so an N-shard pool is bitwise-identical to the
-1-shard pool (tests/test_pool_sharded.py). CPU CI gets N host devices
-via ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+CPU backend). The row scatters, gathers and planning ticks are
+per-device programs too (`jax_engine.row_programs`, at shard-local
+indices): no row leaves its device and the folded slab is never
+reshaped on the device. Rows are independent sessions — there is no
+cross-shard communication anywhere — so an N-shard pool is
+bitwise-identical to the 1-shard pool (tests/test_pool_sharded.py,
+tests/test_pool_fleet_sharded.py). CPU CI gets N host devices via
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 
 Async double-buffered dispatch (ISSUE 6, default ON): `advance` ENQUEUES
 the fleet dispatch and returns without downloading the tiny control
@@ -153,6 +155,18 @@ def _tree_nbytes(tree) -> int:
                    for leaf in jax.tree_util.tree_leaves(tree)))
 
 
+def _bucket(n: int) -> int:
+    """The row count a scatter or gather of `n` rows is padded to: the
+    next power of two, so a slab of B rows compiles at most
+    log2(B) + 1 programs of each, all at build time."""
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def _stack(trees):
+    """Stack host row trees on a new leading axis."""
+    return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *trees)
+
+
 class PoolFullError(RuntimeError):
     """The pool is at its admission cap (`max_sessions` live rows).
 
@@ -217,6 +231,8 @@ class SessionPool:
                 raise ValueError("shards must be >= 1")
             self._mesh = None
             self._sharding = None
+        # the row scatter, gather and planning tick for this layout
+        self._rows = jax_engine.row_programs(self._mesh)
         self._async = bool(async_dispatch)
         if features is not None and len(features) == 3:
             # pre-topology callers pinned (pfw, dyn, abl); the fabric
@@ -247,15 +263,14 @@ class SessionPool:
             [None] * self.max_sessions
         self._free = list(range(self.max_sessions))
         self._blank_rows: set = set()
-        self._tb = None        # TraceBatch, DEVICE leaves (authoritative)
-        # EngineState, DEVICE leaves (authoritative). A sharded pool
-        # stores it in DISPATCH LAYOUT — folded (shards, B/shards, ...)
-        # with shard i on device i — so the pmap chain consumes and
-        # produces it with ZERO per-dispatch reshapes; sync points
-        # unfold on demand (`_state_flat`)
+        # TraceBatch and EngineState, DEVICE leaves (authoritative). A
+        # sharded pool stores both in DISPATCH LAYOUT — folded (shards,
+        # B/shards, ...) with shard i on device i — which the pmap
+        # dispatch and the per-device row scatters and gathers consume
+        # and produce as they are: no reshape on the device, ever
+        self._tb = None
         self._state = None
-        self._tb_disp = None   # folded view of _tb (dispatch cache)
-        self._ep_disp = None   # folded view of _ep_stack
+        self._ep_disp = None   # folded copy of _ep_stack (sharded pool)
         self._scratch = None   # 1-row numpy TraceBatch packing stage
         # tiny host control mirrors, refreshed from each dispatch's
         # status download: per-row relative tick (the no-op horizon for
@@ -285,11 +300,15 @@ class SessionPool:
         # and the dispatches' work counters (`jax_engine.WorkCounts`,
         # added at each ctl download): event steps of the longest device
         # loop, and per-row sums of open-lane steps, admission trips,
-        # work-conservation candidates and the fills among them
+        # serial work-conservation candidates and the fills among them,
+        # and max-min fill candidates; `scatter_rows` counts the rows
+        # the bucketed row scatters wrote, `pad_rows` the no-op pads
+        # among them
         self.io = dict(full_uploads=0, row_uploads=0, row_downloads=0,
                        upload_bytes=0, download_bytes=0, ctl_bytes=0,
                        dispatches=0, event_steps=0, lane_steps=0,
-                       admit_trips=0, wc_trips=0, wc_fills=0)
+                       admit_trips=0, wc_trips=0, wc_fills=0,
+                       maxmin_cands=0, scatter_rows=0, pad_rows=0)
 
     def _resolve(self, params: Optional[SchedulerParams],
                  mechanisms: Optional[dict]) -> tuple:
@@ -597,6 +616,7 @@ class SessionPool:
         self.io["admit_trips"] += int(counts.admit_trips.sum())
         self.io["wc_trips"] += int(counts.wc_trips.sum())
         self.io["wc_fills"] += int(counts.wc_fills.sum())
+        self.io["maxmin_cands"] += int(counts.maxmin_cands.sum())
         return tick_h, fin_h
 
     @_io_accounted
@@ -606,13 +626,13 @@ class SessionPool:
         self._ensure()
         mask = np.zeros(self.max_sessions, bool)
         mask[sess._row] = True
-        state, admitted = self._je.session_plan_tick(
-            self._state_flat(), self._tb, self._ep_stack,
-            kernel=self.kernel,
-            features=self._features_now, row_mask=mask)
-        self._state = self._fold_state(state)
+        tb, ep = self._dispatch_slab()
+        state, admitted = self._rows.plan_tick(
+            self._state, tb, ep, kernel=self.kernel,
+            features=self._features_now, row_mask=self._fold(mask))
+        self._state = state
         self.io["dispatches"] += 1
-        adm_all = np.asarray(admitted)
+        adm_all = self._unfold(np.asarray(admitted))
         self.io["ctl_bytes"] += adm_all.nbytes
         sess._host_stale = True
         self._materialize([sess])
@@ -673,8 +693,8 @@ class SessionPool:
                         else e._replace(dp=e.dp._replace(
                             clairvoyant=jnp.float32(1.0)))
                         for e in rows]
-            self._ep_stack = self._place(jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *rows))
+            self._ep_stack = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *rows)
             self._ep_disp = None
 
     @_io_accounted
@@ -703,49 +723,83 @@ class SessionPool:
             for r, row in st_rows:
                 self._ticks[r] = int(row.tick)
                 self._fin[r] = row.finished
-            # ONE SCATTER QUEUE PER SHARD: staged rows funnel through
-            # their owning shard's fused scatter (the unsharded pool
-            # keeps the single fused call — exactly the pre-shard
-            # dispatch shape)
-            per = self.max_sessions // self.shards
-            buckets: dict = {}
-            for r, row in tb_rows:
-                buckets.setdefault(r // per, ([], []))[0].append((r, row))
-            for r, row in st_rows:
-                buckets.setdefault(r // per, ([], []))[1].append((r, row))
-            # per shard: (tb_idx, tb_payload, st_idx, st_payload), the
-            # TraceBatch pair None where the shard re-packed no row
-            staged = []
-            for sh in sorted(buckets):
-                tb_g, st_g = buckets[sh]
-                st_idx = np.array([r for r, _ in st_g], np.int32)
-                st_payload = jax.tree_util.tree_map(
-                    lambda *xs: np.stack(xs), *[p for _, p in st_g])
-                self.io["upload_bytes"] += _tree_nbytes(st_payload)
-                tb_idx = tb_payload = None
-                if tb_g:
-                    tb_idx = np.array([r for r, _ in tb_g], np.int32)
-                    tb_payload = stack_rows([p for _, p in tb_g])
-                    self.io["row_uploads"] += len(tb_g)
-                    self.io["upload_bytes"] += _tree_nbytes(tb_payload)
-                staged.append((tb_idx, tb_payload, st_idx, st_payload))
+            # ONE SCATTER QUEUE PER SHARD, at shard-local rows (a
+            # sharded pool's scatter runs per device on its folded
+            # slab). Every queue is padded to one power-of-two bucket
+            # (`_bucket`) with pad rows past its shard's last row, which
+            # the scatter drops: each count the slab can see ran when
+            # it was built (`_warm_rows`)
+            st_q = self._by_shard(st_rows)
+            k = _bucket(max(len(q) for q in st_q))  # tb rows <= st rows
+            st_idx, st_payload = self._pad_rows(st_q, k, st_rows[0][1],
+                                                _stack)
+            self.io["scatter_rows"] += self.shards * k
+            self.io["pad_rows"] += self.shards * k - len(st_rows)
+            self.io["upload_bytes"] += _tree_nbytes(st_payload)
+            if tb_rows:
+                tb_idx, tb_payload = self._pad_rows(
+                    self._by_shard(tb_rows), k, tb_rows[0][1], stack_rows)
+                self.io["row_uploads"] += len(tb_rows)
+                self.io["upload_bytes"] += _tree_nbytes(tb_payload)
         with TraceAnnotation(SPAN_UPLOAD):
-            st = self._state_flat()
-            for tb_idx, tb_payload, st_idx, st_payload in staged:
-                if tb_idx is not None:
-                    # one fused scatter dispatch covers both trees
-                    self._tb, st = self._je.scatter_rows(
-                        (self._tb, st), (tb_idx, st_idx),
-                        (tb_payload, st_payload))
-                else:
-                    st = self._je.scatter_rows(st, st_idx, st_payload)
-        if self._sharding is not None:
-            # keep the slab pinned to its row sharding between
-            # dispatches (a no-op when the scatter preserved it) and
-            # drop the folded dispatch cache the scatter invalidated
-            self._tb = self._place(self._tb)
-            self._tb_disp = None
-        self._state = self._fold_state(st)
+            if tb_rows:
+                # one fused scatter dispatch covers both trees
+                self._tb, self._state = self._rows.scatter(
+                    (self._tb, self._state), (tb_idx, st_idx),
+                    (tb_payload, st_payload))
+            else:
+                self._state = self._rows.scatter(self._state, st_idx,
+                                                 st_payload)
+
+    def _by_shard(self, items) -> list:
+        """[(row, x)] -> one list per shard of (shard-local row, x)."""
+        per = self.max_sessions // self.shards
+        out = [[] for _ in range(self.shards)]
+        for r, x in items:
+            out[r // per].append((r % per, x))
+        return out
+
+    def _pad_rows(self, queues, k: int, pad, stack) -> tuple:
+        """Shard-local indices and stacked payload of the per-shard
+        `queues` [(local row, tree)], each padded to `k` rows with
+        `pad` at local index B/shards, past its shard's last row, where
+        the scatter drops it: pads leave the slab bit for bit as it
+        was. In the slab's layout (`_fold`): (k,) and (k, ...)
+        unsharded, (shards, k) and (shards, k, ...) sharded."""
+        per = self.max_sessions // self.shards
+        idx = np.full((self.shards, k), per, np.int32)
+        trees = []
+        for sh, q in enumerate(queues):
+            idx[sh, :len(q)] = [r for r, _ in q]
+            trees += [t for _, t in q] + [pad] * (k - len(q))
+        return self._fold(idx.reshape(-1)), self._fold(stack(trees))
+
+    def _warm_rows(self) -> None:
+        """Run the fused and the state-only row scatter and the row
+        gather once at every bucket a shard's queue can fill, on the
+        slab just built, with pad rows only (which change nothing): a
+        serving window then compiles and loads nothing, whatever the
+        number of dirty or stale rows. (A call, not an ahead-of-time
+        compile: a pmapped program compiled ahead of time is compiled
+        again at its first call.)"""
+        from repro.traces.batch import row_of, stack_rows
+
+        self._blank_scratch()
+        tb_pad = row_of(self._scratch, 0)
+        st_pad = self._blank_state_row()
+        queues = [[] for _ in range(self.shards)]
+        k = 1
+        while k <= _bucket(self.max_sessions // self.shards):
+            tb_idx, tb_rows = self._pad_rows(queues, k, tb_pad, stack_rows)
+            st_idx, st_rows = self._pad_rows(queues, k, st_pad, _stack)
+            self.io["upload_bytes"] += (_tree_nbytes(tb_rows)
+                                        + 2 * _tree_nbytes(st_rows))
+            self._tb, self._state = self._rows.scatter(
+                (self._tb, self._state), (tb_idx, st_idx),
+                (tb_rows, st_rows))
+            self._state = self._rows.scatter(self._state, st_idx, st_rows)
+            self._rows.gather(self._state, np.zeros_like(st_idx))
+            k *= 2
 
     def _scratch_tb(self):
         from repro.traces.batch import empty_batch
@@ -788,36 +842,24 @@ class SessionPool:
                 self._pack_row_np(tb, s._row, s)
                 rows[s._row] = self._state_row(s)
                 s._state_dirty = False
-            state = jax.tree_util.tree_map(lambda *xs: np.stack(xs),
-                                           *rows)
+            state = _stack(rows)
         self.io["full_uploads"] += 1
         self.io["upload_bytes"] += _tree_nbytes(tb) + _tree_nbytes(state)
         # the upload pins the row sharding: each shard receives exactly
-        # its own rows (sharding=None -> default single-device slab);
-        # the state uploads directly in dispatch layout (the fold is a
-        # free host-side numpy reshape)
+        # its own rows (sharding=None -> default single-device slab),
+        # in dispatch layout (the fold is a free host-side numpy reshape)
         with TraceAnnotation(SPAN_UPLOAD):
-            self._tb = jax.device_put(tb, self._sharding)
-            self._tb_disp = None
+            self._tb = jax.device_put(self._fold(tb), self._sharding)
             self._state = jax.device_put(self._fold(state),
                                          self._sharding)
         self._ticks = state.tick.copy()
         self._fin = state.finished.copy()
-
-    def _place(self, tree):
-        """Re-pin a slab tree to the pool's row sharding (identity for
-        an unsharded pool). `PartitionSpec("rows")` partitions dim 0,
-        so the same sharding pins flat (B, ...) trees (one row block
-        per device) and folded (shards, B/shards, ...) trees (one
-        shard index per device) identically."""
-        if self._sharding is None:
-            return tree
-        return jax.device_put(tree, self._sharding)
+        self._warm_rows()
 
     def _fold(self, tree):
-        """Reshape every leaf (B, ...) -> (shards, B/shards, ...): the
-        pmap dispatch layout of a sharded pool (identity when
-        unsharded). Shard-local on a row-sharded leaf — no rows move."""
+        """Reshape every leaf of a HOST tree (B, ...) -> (shards,
+        B/shards, ...): the pmap dispatch layout of a sharded pool
+        (identity when unsharded)."""
         if self.shards <= 1:
             return tree
         S = self.shards
@@ -826,39 +868,24 @@ class SessionPool:
             tree)
 
     def _unfold(self, tree):
-        """Inverse of `_fold`: dispatch layout back to flat rows."""
+        """Inverse of `_fold` on a HOST tree."""
         if self.shards <= 1:
             return tree
         return jax.tree_util.tree_map(
-            lambda x: x.reshape(x.shape[0] * x.shape[1],
-                                *x.shape[2:]), tree)
-
-    def _fold_state(self, flat):
-        """Flat engine state -> stored dispatch layout, re-pinned so
-        shard i lives on mesh device i."""
-        if self.shards <= 1:
-            return flat
-        return jax.device_put(self._fold(flat), self._sharding)
-
-    def _state_flat(self):
-        """The engine state as flat (B, ...) rows — what the
-        row-indexed sync machinery (gather/scatter/plan/host_view)
-        operates on. A device-side reshape for a sharded pool; the
-        identity otherwise."""
-        return self._unfold(self._state)
+            lambda x: x.reshape(x.shape[0] * x.shape[1], *x.shape[2:]),
+            tree)
 
     def _dispatch_slab(self):
-        """The (tb, ep) pair in dispatch layout — folded views cached
-        until the flat authoritative trees change (they change only on
-        scatter/rebuild/membership churn, never per advance, so the
-        async dispatch hot loop performs no reshapes at all)."""
+        """The (tb, ep) pair in dispatch layout: the stored slab, and
+        the stacked parameters folded and placed once per restack (they
+        change only on membership churn, never per advance, so the
+        async dispatch hot loop moves no parameters)."""
         if self.shards <= 1:
             return self._tb, self._ep_stack
-        if self._tb_disp is None:
-            self._tb_disp = self._place(self._fold(self._tb))
         if self._ep_disp is None:
-            self._ep_disp = self._place(self._fold(self._ep_stack))
-        return self._tb_disp, self._ep_disp
+            self._ep_disp = jax.device_put(self._fold(self._ep_stack),
+                                           self._sharding)
+        return self._tb, self._ep_disp
 
     def _pack_row_np(self, tb, r: int, s) -> None:
         """Pack one session's live coflows into row `r` of a NUMPY
@@ -955,13 +982,24 @@ class SessionPool:
         if not stale:
             return
         with TraceAnnotation(SPAN_GATHER):
-            idx = np.array([s._row for s in stale], np.int32)
-            rows = self._je.gather_rows(self._state_flat(), idx)
-            host = jax.tree_util.tree_map(np.asarray, rows)
+            # one queue per shard at shard-local rows, padded to one
+            # bucket like the scatters (`_bucket`); the pads gather the
+            # shard's first row and are not read
+            queues = self._by_shard((s._row, s) for s in stale)
+            k = _bucket(max(len(q) for q in queues))
+            idx = np.zeros((self.shards, k), np.int32)
+            at = {}
+            for sh, q in enumerate(queues):
+                for j, (r, s) in enumerate(q):
+                    idx[sh, j] = r
+                    at[s] = sh * k + j
+            rows = self._rows.gather(self._state,
+                                     self._fold(idx.reshape(-1)))
+            host = self._unfold(jax.tree_util.tree_map(np.asarray, rows))
             self.io["row_downloads"] += len(stale)
             self.io["download_bytes"] += _tree_nbytes(host)
-            for j, s in enumerate(stale):
-                self._sync_row(s, host, j)
+            for s in stale:
+                self._sync_row(s, host, at[s])
                 s._host_stale = False
                 s._new_done = False
                 self._fresh.discard(s)
@@ -1016,8 +1054,9 @@ class SessionPool:
         if self._tb is None:
             return None, None
         self._sync_ctl()
-        tb_h = jax.tree_util.tree_map(np.asarray, self._tb)
-        st_h = jax.tree_util.tree_map(np.asarray, self._state_flat())
+        # a sharded slab comes back folded: unfold it on the host
+        tb_h, st_h = self._unfold(jax.tree_util.tree_map(
+            np.asarray, (self._tb, self._state)))
         # a full-slab download: account it like any other host pull so
         # `pool.io` stays the single source of truth for transfers
         self.io["download_bytes"] += _tree_nbytes(tb_h) + _tree_nbytes(st_h)

@@ -44,6 +44,7 @@ SCOPE_ORDER = "saath.tick.order"
 SCOPE_ADMIT = "saath.tick.admit"
 SCOPE_WC_ORDER = "saath.tick.wc_order"
 SCOPE_WC_FILL = "saath.tick.wc_fill"
+SCOPE_MAXMIN = "saath.tick.maxmin"
 
 
 class CoordParams(NamedTuple):
@@ -397,10 +398,12 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
 
     # D4 work conservation over the missed list (lines 18-23), gated by
     # dp.wc via the candidate count (zero iterations when the switch is
-    # off). `n_cand` counts the candidates offered to the fill (0 for
-    # the max-min fill, which has no serial loop), `n_fill` those it
-    # gave a rate.
+    # off). `n_cand` counts the candidates offered to the serial fill
+    # and `n_fill` those it gave a rate (both 0 for the max-min fill);
+    # `n_maxmin` the candidate flows offered to the max-min fill (0 for
+    # the serial ones).
     wc_on = dp.wc > 0
+    n_maxmin = jnp.int32(0)
     if flows is None:
         # coflow-granular fallback: one equal rate across all live flows
         # of each missed coflow (the faithful collective-coflow mapping)
@@ -444,7 +447,7 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
             # progressive filling otherwise. Incidence rows stack ports
             # then uplinks/downlinks; the sentinel leaf id Lf one-hots
             # to a zero column, so intra-leaf flows see ports only.
-            with jax.named_scope(SCOPE_WC_FILL):
+            with jax.named_scope(SCOPE_MAXMIN):
                 a_send = jax.nn.one_hot(flows.src, P, axis=0,
                                         dtype=jnp.float32)
                 a_recv = jax.nn.one_hot(flows.dst, P, axis=0,
@@ -466,6 +469,7 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
                     a_send, a_recv, cand0, bw_s_ext, bw_r_ext,
                     force=kernel)
                 wc_flow = jnp.where(cand0, wc_flow, 0.0)
+                n_maxmin = cand0.sum().astype(jnp.int32)
             n_cand = n_fill = jnp.int32(0)
         else:
             with jax.named_scope(SCOPE_WC_ORDER):
@@ -496,11 +500,13 @@ def tick_core(state: CoordState, batch: CoflowBatch, now: jax.Array,
 
     new_state = CoordState(queue=jnp.where(act, q, state.queue),
                            deadline=deadline, running=admitted)
-    # n_live / n_cand / n_fill: the admission loop's trips, the
-    # work-conservation fill's candidates and the ones it gave a rate
-    # this tick (the work counters `jax_engine` sums per row)
+    # n_live / n_cand / n_fill / n_maxmin: the admission loop's trips,
+    # the serial work-conservation fill's candidates and the ones it
+    # gave a rate, and the max-min fill's candidates this tick (the
+    # work counters `jax_engine` sums per row)
     out = {"rate": rate, "wc_rate": wc_rate, "wc_flow": wc_flow,
            "admitted": admitted, "queue": q, "contention": k,
            "expired": expired, "order": perm,
-           "n_live": n_live, "n_cand": n_cand, "n_fill": n_fill}
+           "n_live": n_live, "n_cand": n_cand, "n_fill": n_fill,
+           "n_maxmin": n_maxmin}
     return new_state, out

@@ -29,7 +29,7 @@ full reference configuration.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -149,17 +149,20 @@ class WorkCounts(NamedTuple):
     - `lane_steps`: iterations in which the row's lane was open (short
       of its horizon with unfinished coflows);
     - `admit_trips`: admission while_loop trips (live coflows per tick);
-    - `wc_trips`: candidates offered to the work-conservation fill
-      (candidate flows per tick for the per-flow greedy fill, live
+    - `wc_trips`: candidates offered to the serial work-conservation
+      fill (candidate flows per tick for the per-flow greedy fill, live
       coflows for the coflow-granular one, 0 for the max-min fill);
     - `wc_fills`: the candidates the fill gave a rate (the per-flow
       greedy fill's loop trips, at most 2P (+ 2L) a tick; 0 for the
-      max-min fill)."""
+      max-min fill);
+    - `maxmin_cands`: candidate flows offered to the max-min fill per
+      tick (0 for the serial fills)."""
     event_steps: jax.Array
     lane_steps: jax.Array
     admit_trips: jax.Array
     wc_trips: jax.Array
     wc_fills: jax.Array
+    maxmin_cands: jax.Array
 
 
 # ---- single-trace tick ---------------------------------------------------
@@ -383,9 +386,9 @@ def _tick(state: EngineState, tb: TraceBatch, ep: EngineParams,
     so incremental replay is bitwise the offline scan. `None` (offline
     replay) compiles both the cap and the pending machinery out.
 
-    Returns (new state, (admission trips, work-conservation candidates,
-    work-conservation fills)) of the step's coordinator tick
-    (`tick_core`'s work counts).
+    Returns (new state, (admission trips, serial work-conservation
+    candidates and fills, max-min candidates)) of the step's
+    coordinator tick (`tick_core`'s work counts).
     """
     session = n_end is not None
     delta = ep.delta
@@ -402,7 +405,7 @@ def _tick(state: EngineState, tb: TraceBatch, ep: EngineParams,
     coord, out = jc.tick_core(state.coord, batch, now, ep.dp,
                               kernel=kernel, flows=flows,
                               wc_fill="maxmin" if wc_maxmin else "greedy")
-    trips = (out["n_live"], out["n_cand"], out["n_fill"])
+    trips = (out["n_live"], out["n_cand"], out["n_fill"], out["n_maxmin"])
     with jax.named_scope(SCOPE_HORIZON):
         # per-flow rates: MADD equal rate for admitted coflows + the work-
         # conservation fill (flow-granular when per_flow_wc, else the
@@ -790,6 +793,20 @@ def _drive(tb: TraceBatch, ep: EngineParams, delta: float,
 
 # ---- online session support (repro.api.SaathSession) ---------------------
 
+def _set_rows(tree, idx, rows):
+    if type(tree) is tuple:       # NamedTuple slabs are leaves-bearing
+        return tuple(
+            jax.tree_util.tree_map(
+                lambda a, u, i=i: a.at[i].set(u), t, r)
+            for t, i, r in zip(tree, idx, rows))
+    return jax.tree_util.tree_map(lambda a, u: a.at[idx].set(u),
+                                  tree, rows)
+
+
+def _get_rows(tree, idx):
+    return jax.tree_util.tree_map(lambda a: a[idx], tree)
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def scatter_rows(tree, idx, rows):
     """Write stacked row updates into a device-resident slab pytree.
@@ -804,14 +821,9 @@ def scatter_rows(tree, idx, rows):
     the rows whose membership/state changed cross the host-device
     boundary; clean rows never re-upload (DESIGN.md §8). The input
     tree is DONATED — XLA updates the slab buffers in place, so a
-    scatter costs O(dirty rows), not O(slab); callers must rebind."""
-    if type(tree) is tuple:       # NamedTuple slabs are leaves-bearing
-        return tuple(
-            jax.tree_util.tree_map(
-                lambda a, u, i=i: a.at[i].set(u), t, r)
-            for t, i, r in zip(tree, idx, rows))
-    return jax.tree_util.tree_map(lambda a, u: a.at[idx].set(u),
-                                  tree, rows)
+    scatter costs O(dirty rows), not O(slab); callers must rebind.
+    Indices past the last row are dropped (the pool's pad rows)."""
+    return _set_rows(tree, idx, rows)
 
 
 @jax.jit
@@ -819,7 +831,7 @@ def gather_rows(tree, idx: jax.Array):
     """Slice rows `idx` out of a device-resident slab pytree (stacked on
     axis 0) — the download half of the `SessionPool` row contract: the
     host mirrors only the rows a caller actually inspects."""
-    return jax.tree_util.tree_map(lambda a: a[idx], tree)
+    return _get_rows(tree, idx)
 
 
 def features_for(params: SchedulerParams, *, fidelity: str = "flow",
@@ -881,7 +893,7 @@ def _session_while(state: EngineState, tb: TraceBatch, ep: EngineParams,
     def body(carry):
         s, steps, c = carry
         opened = lane_open(s).astype(jnp.int32)
-        s, (n_live, n_cand, n_fill) = jax.vmap(
+        s, (n_live, n_cand, n_fill, n_mm) = jax.vmap(
             lambda srow, tbrow, nerow, eprow: _tick(
                 srow, tbrow, eprow, kernel, per_flow_wc=per_flow_wc,
                 with_dynamics=with_dynamics,
@@ -890,7 +902,7 @@ def _session_while(state: EngineState, tb: TraceBatch, ep: EngineParams,
                     s, tb, n_end, ep)
         c = WorkCounts(c.event_steps + 1, c.lane_steps + opened,
                        c.admit_trips + n_live, c.wc_trips + n_cand,
-                       c.wc_fills + n_fill)
+                       c.wc_fills + n_fill, c.maxmin_cands + n_mm)
         return s, steps + 1, c
 
     with jax.named_scope(SCOPE_SESSION):
@@ -1025,6 +1037,58 @@ def session_advance(state: EngineState, tb: TraceBatch, ep: EngineParams,
     return state, steps, counts
 
 
+class RowPrograms(NamedTuple):
+    """The programs a `SessionPool` moves single rows of its slab with,
+    chosen once for the slab's layout (`row_programs`):
+
+    - `scatter(tree, idx, rows)`: `scatter_rows` (DONATES the slab);
+    - `gather(tree, idx)`: `gather_rows`;
+    - `plan_tick(state, tb, ep, *, kernel, features, row_mask)`:
+      `session_plan_tick`.
+
+    On a FOLDED row-sharded slab (every leaf ``(shards, B/shards,
+    ...)``, shard i on mesh device i) `idx` is ``(shards, k)`` at
+    shard-local rows, payloads, gathered rows and `row_mask` carry the
+    same leading shard axis, and each device runs the single-slab
+    program on its own rows: no row leaves its device and no collective
+    is compiled in."""
+    scatter: Callable
+    gather: Callable
+    plan_tick: Callable
+
+
+@functools.lru_cache(maxsize=None)
+def row_programs(mesh=None) -> RowPrograms:
+    """`RowPrograms` for a slab on one device (`mesh` None: the jitted
+    programs) or folded over a `row_mesh` (one pmapped program per
+    device, like `_pmapped_session_block`)."""
+    if mesh is None:
+        return RowPrograms(scatter_rows, gather_rows, session_plan_tick)
+    devices = list(np.asarray(mesh.devices).flat)
+
+    def plan_tick(state, tb, ep, *, kernel, features, row_mask):
+        return _pmapped_plan_tick(kernel, tuple(features), mesh)(
+            state, tb, ep, row_mask)
+
+    return RowPrograms(
+        jax.pmap(_set_rows, axis_name="rows", devices=devices,
+                 donate_argnums=(0,)),
+        jax.pmap(_get_rows, axis_name="rows", devices=devices),
+        plan_tick)
+
+
+@functools.lru_cache(maxsize=None)
+def _pmapped_plan_tick(kernel: Optional[str], features: tuple, mesh):
+    """`session_plan_tick` per device over a folded row-sharded slab."""
+    devices = list(np.asarray(mesh.devices).flat)
+
+    def plan(state, tb, ep, row_mask):
+        return session_plan_tick(state, tb, ep, kernel=kernel,
+                                 features=features, row_mask=row_mask)
+
+    return jax.pmap(plan, axis_name="rows", devices=devices)
+
+
 @functools.partial(jax.jit, static_argnames=("kernel", "features"))
 def session_plan_tick(state: EngineState, tb: TraceBatch,
                       ep: EngineParams, *, kernel: Optional[str] = None,
@@ -1069,4 +1133,4 @@ def session_plan_tick(state: EngineState, tb: TraceBatch,
 __all__ = ["EngineParams", "EngineState", "EngineResult", "WorkCounts",
            "default_max_ticks", "features_for", "resolve_kernel",
            "session_advance", "session_plan_tick", "scatter_rows",
-           "gather_rows"]
+           "gather_rows", "RowPrograms", "row_programs"]

@@ -162,17 +162,17 @@ def test_rearming_the_ctl_revokes_the_sync_fact():
 def test_slab_rewrite_without_cache_invalidation():
     assert coh.R_CACHE in rules_of(pool="""
         class SessionPool:
-            def _rebuild(self, tb):
-                self._tb = tb
+            def _restack(self, ep):
+                self._ep_stack = ep
     """)
 
 
 def test_slab_rewrite_with_cache_invalidation_is_clean():
     assert coh.R_CACHE not in rules_of(pool="""
         class SessionPool:
-            def _rebuild(self, tb):
-                self._tb = tb
-                self._tb_disp = None
+            def _restack(self, ep):
+                self._ep_stack = ep
+                self._ep_disp = None
     """)
 
 

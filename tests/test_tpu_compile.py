@@ -110,3 +110,61 @@ def test_session_advance_compiles_for_v5e_with_pallas(one_chip):
         _spec(one_chip, (), jnp.int32), kernel="pallas",
         features=(True, True, False, True, False)).compile()
     assert _kernels_in(compiled) == 2
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    """A 1-D "rows" mesh over the four chips of a described v5e 2x2."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.Mesh(np.array(topo.devices), ("rows",))
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def test_sharded_row_programs_compile_for_v5e_without_collectives(
+        four_chips):
+    """The row-sharded pool's sync points on the 256-tenant serving
+    slab folded over a 2x2 v5e: the fused and the state-only row
+    scatter, the row gather and the planning tick (kernels on) compile
+    to one program per chip that moves no row between chips."""
+    from repro.analysis.audit import _canonical_slab
+    from repro.fabric import jax_engine as je
+
+    shards, k = 4, 8
+    per = SERVE_B // shards
+    tb, _, ep, state = _canonical_slab(
+        leaf_links=SERVE_LF, b=per, f=SERVE_F, c=SERVE_C, p=SERVE_P)
+
+    def folded(tree, n):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                (shards, n) + np.shape(x)[1:], np.asarray(x).dtype), tree)
+
+    progs = je.row_programs(four_chips)
+    tb_s, st_s, ep_s = (folded(t, per) for t in (tb, state, ep))
+    idx = jax.ShapeDtypeStruct((shards, k), jnp.int32)
+    plan = je._pmapped_plan_tick("pallas", (True, True, False, True, False),
+                                 four_chips)
+    compiled = [
+        progs.scatter.lower((tb_s, st_s), (idx, idx),
+                            (folded(tb, k), folded(state, k))).compile(),
+        progs.scatter.lower(st_s, idx, folded(state, k)).compile(),
+        progs.gather.lower(st_s, idx).compile(),
+        plan.lower(st_s, tb_s, ep_s, jax.ShapeDtypeStruct(
+            (shards, per), jnp.bool_)).compile()]
+    for op in ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter"):
+        assert not any(op in c.as_text() for c in compiled), op
+    assert _kernels_in(compiled[3]) >= 1
